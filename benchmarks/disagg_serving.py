@@ -163,8 +163,8 @@ def engine_demo(quick: bool = False) -> dict:
     rng = np.random.default_rng(0)
     B, Smax, T, Hq, Hkv, hd = 3, 32, 8, 8, 2, 16
     q = jnp.asarray(rng.normal(size=(B, T, Hq, hd)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(B, Smax, Hkv, hd)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(B, Smax, Hkv, hd)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(B, Hkv, Smax, hd)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(B, Hkv, Smax, hd)), jnp.float32)
     start = jnp.asarray([0, 5, 11], jnp.int32)
     qlen = jnp.asarray([T, T - 3, T], jnp.int32)
     ref = ragged_prefill_ref(q, k, v, start, qlen)

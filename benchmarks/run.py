@@ -11,6 +11,8 @@ import argparse
 import sys
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from . import common
 from . import (chaos_serving, disagg_serving, fig5_heatmap, fig6_kernels,
                fig7_speedup, fig8_interference, fig9_vgg_scaling,
@@ -43,6 +45,7 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default="")
     args = ap.parse_args()
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failed = []
     for name, mod in MODULES:

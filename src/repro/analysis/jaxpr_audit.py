@@ -141,7 +141,7 @@ def cache_leaf_names(cache_spec) -> list:
 
 def _iter_jaxprs(jaxpr):
     """Yield a jaxpr and every sub-jaxpr nested in its eqn params."""
-    import jax.core as jc
+    import jax.extend.core as jc
     stack = [jaxpr]
     while stack:
         jx = stack.pop()

@@ -95,17 +95,6 @@ class AxisRules:
         return P(*parts)
 
 
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions (``experimental.shard_map``
-    with ``check_rep`` before 0.5); replication checking disabled."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
-
 def _size(mesh: Mesh, axes: tuple[str, ...]) -> int:
     t = 1
     for a in axes:

@@ -16,7 +16,12 @@ blocks only up to each slot's position:
   block, which makes the pipeline re-issue an already-resident tile instead
   of DMA'ing dead cache rows, and ``pl.when`` skips their compute entirely;
 * GQA is folded into the q/out block shape ``(rep, hd)`` with K/V indexed by
-  the Hkv grid axis — no KV head replication ever hits HBM.
+  the Hkv grid axis — no KV head replication ever hits HBM;
+* the cache is head-major ``(B, Hkv, Smax, hd)``, so a K/V block
+  ``(1, 1, bk, hd)`` has ``(bk, hd)`` as its last two dimensions — the
+  layout Mosaic's tiling rule accepts (a sequence-major cache would need a
+  ``(bk, 1, hd)`` block whose second-minor 1 is neither a multiple of 8
+  nor the full Hkv).
 
 VMEM per step: q (rep,hd) + k,v (bk,hd) + scores (rep,bk) f32 + acc (rep,hd)
 f32 — tiny; the kernel is bandwidth-bound on the cache read, which is exactly
@@ -53,8 +58,8 @@ def _ragged_decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
 
     def _step():
         q = q_ref[0, 0]                                   # (rep, hd)
-        k = k_ref[0, :, 0, :]                             # (bk, hd)
-        v = v_ref[0, :, 0, :]
+        k = k_ref[0, 0]                                   # (bk, hd)
+        v = v_ref[0, 0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # (rep, bk)
@@ -83,15 +88,15 @@ def ragged_decode_pallas(q: jax.Array, k_cache: jax.Array,
                          v_cache: jax.Array, pos: jax.Array, *,
                          block_k: int = 128,
                          interpret: bool = False) -> jax.Array:
-    """q: (B, Hkv, rep, hd); k,v: (B, Smax, Hkv, hd); pos: (B,) int32
+    """q: (B, Hkv, rep, hd); k,v: (B, Hkv, Smax, hd); pos: (B,) int32
     (index of each slot's newest live token).  Returns (B, Hkv, rep, hd)
     float32."""
     B, Hkv, rep, hd = q.shape
-    Smax = k_cache.shape[1]
+    Smax = k_cache.shape[2]
     bk = min(block_k, Smax)
     pad = (-Smax) % bk
     if pad:                       # padded rows sit past any pos: masked off
-        widths = ((0, 0), (0, pad), (0, 0), (0, 0))
+        widths = ((0, 0), (0, 0), (0, pad), (0, 0))
         k_cache = jnp.pad(k_cache, widths)
         v_cache = jnp.pad(v_cache, widths)
     n_k = (Smax + pad) // bk
@@ -99,15 +104,15 @@ def ragged_decode_pallas(q: jax.Array, k_cache: jax.Array,
     def kv_map(b, g, ki, pos_ref):
         # clamp dead blocks onto the slot's last live block: the pipeline
         # re-issues a resident tile instead of streaming unused cache rows
-        return (b, jnp.minimum(ki, pos_ref[b] // bk), g, 0)
+        return (b, g, jnp.minimum(ki, pos_ref[b] // bk), 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, Hkv, n_k),
         in_specs=[
             pl.BlockSpec((1, 1, rep, hd), lambda b, g, ki, pos_ref: (b, g, 0, 0)),
-            pl.BlockSpec((1, bk, 1, hd), kv_map),
-            pl.BlockSpec((1, bk, 1, hd), kv_map),
+            pl.BlockSpec((1, 1, bk, hd), kv_map),
+            pl.BlockSpec((1, 1, bk, hd), kv_map),
         ],
         out_specs=pl.BlockSpec((1, 1, rep, hd),
                                lambda b, g, ki, pos_ref: (b, g, 0, 0)),

@@ -38,13 +38,14 @@ def ragged_decode_attention(q: jax.Array, k_cache: jax.Array,
                             block_k: int = 128) -> jax.Array:
     """One-token GQA attention against a ragged batch cache.
 
-    q: (B, Hq, hd); k,v: (B, Smax, Hkv, hd); pos: (B,) int32 index of each
-    slot's newest live token (inclusive).  Returns (B, Hq, hd) float32.
+    q: (B, Hq, hd); k,v: (B, Hkv, Smax, hd) head-major; pos: (B,) int32
+    index of each slot's newest live token (inclusive).  Returns
+    (B, Hq, hd) float32.
     """
     on_tpu = jax.default_backend() == "tpu"
     if on_tpu or _FORCED:
         B, Hq, hd = q.shape
-        Hkv = k_cache.shape[2]
+        Hkv = k_cache.shape[1]
         rep = Hq // Hkv
         out = ragged_decode_pallas(q.reshape(B, Hkv, rep, hd), k_cache,
                                    v_cache, pos, block_k=block_k,

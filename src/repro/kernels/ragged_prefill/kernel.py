@@ -18,7 +18,9 @@ each slot's live horizon:
   of DMA'ing rows no query can see, and ``pl.when`` skips their compute;
 * GQA folds into the q/out block ``(T*rep, hd)`` — query row ``i`` is chunk
   token ``i // rep`` at absolute position ``start[b] + i // rep``; K/V are
-  indexed by the Hkv grid axis, so no KV-head replication ever hits HBM.
+  indexed by the Hkv grid axis, so no KV-head replication ever hits HBM;
+* the cache is head-major ``(B, Hkv, Smax, hd)`` so each K/V block
+  ``(1, 1, bk, hd)`` tiles its last two dimensions the way Mosaic requires.
 
 Padded chunk rows (``i // rep >= qlen[b]``) are masked out of every score;
 their ``l`` stays 0 and the epilogue's ``acc / max(l, eps)`` writes exact
@@ -58,8 +60,8 @@ def _ragged_prefill_kernel(start_ref, qlen_ref, q_ref, k_ref, v_ref, o_ref,
 
     def _step():
         q = q_ref[0, 0]                                   # (T*rep, hd)
-        k = k_ref[0, :, 0, :]                             # (bk, hd)
-        v = v_ref[0, :, 0, :]
+        k = k_ref[0, 0]                                   # (bk, hd)
+        v = v_ref[0, 0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # (T*rep, bk)
@@ -99,15 +101,15 @@ def ragged_prefill_pallas(q: jax.Array, k_cache: jax.Array,
                           qlen: jax.Array, *, rep: int, block_k: int = 128,
                           interpret: bool = False) -> jax.Array:
     """q: (B, Hkv, T*rep, hd) GQA-folded chunk queries (row ``i`` is chunk
-    token ``i // rep``); k,v: (B, Smax, Hkv, hd); start, qlen: (B,) int32
+    token ``i // rep``); k,v: (B, Hkv, Smax, hd); start, qlen: (B,) int32
     (chunk origin / live rows per slot).  Returns (B, Hkv, T*rep, hd)
     float32 with padded rows zeroed."""
     B, Hkv, tr, hd = q.shape
-    Smax = k_cache.shape[1]
+    Smax = k_cache.shape[2]
     bk = min(block_k, Smax)
     pad = (-Smax) % bk
     if pad:                       # padded rows sit past any horizon: masked
-        widths = ((0, 0), (0, pad), (0, 0), (0, 0))
+        widths = ((0, 0), (0, 0), (0, pad), (0, 0))
         k_cache = jnp.pad(k_cache, widths)
         v_cache = jnp.pad(v_cache, widths)
     n_k = (Smax + pad) // bk
@@ -117,7 +119,7 @@ def ragged_prefill_pallas(q: jax.Array, k_cache: jax.Array,
         # pipeline re-issues a resident tile instead of streaming rows
         # past start + qlen - 1 (max(0, .) guards empty padded slots)
         last = jnp.maximum(start_ref[b] + qlen_ref[b] - 1, 0)
-        return (b, jnp.minimum(ki, last // bk), g, 0)
+        return (b, g, jnp.minimum(ki, last // bk), 0)
 
     def fold_map(b, g, ki, start_ref, qlen_ref):
         return (b, g, 0, 0)
@@ -127,8 +129,8 @@ def ragged_prefill_pallas(q: jax.Array, k_cache: jax.Array,
         grid=(B, Hkv, n_k),
         in_specs=[
             pl.BlockSpec((1, 1, tr, hd), fold_map),
-            pl.BlockSpec((1, bk, 1, hd), kv_map),
-            pl.BlockSpec((1, bk, 1, hd), kv_map),
+            pl.BlockSpec((1, 1, bk, hd), kv_map),
+            pl.BlockSpec((1, 1, bk, hd), kv_map),
         ],
         out_specs=pl.BlockSpec((1, 1, tr, hd), fold_map),
         scratch_shapes=[

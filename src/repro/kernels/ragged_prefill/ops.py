@@ -40,14 +40,15 @@ def ragged_prefill_attention(q: jax.Array, k_cache: jax.Array,
     """Chunked GQA prefill attention against a ragged batch cache.
 
     q: (B, T, Hq, hd) — chunk token ``i`` of slot ``b`` is at absolute
-    position ``start[b] + i``; k,v: (B, Smax, Hkv, hd) caches already
-    holding the chunk's K/V rows; start, qlen: (B,) int32 (chunk origin and
-    live rows).  Returns (B, T, Hq, hd) float32 with padded rows zeroed.
+    position ``start[b] + i``; k,v: (B, Hkv, Smax, hd) head-major caches
+    already holding the chunk's K/V rows; start, qlen: (B,) int32 (chunk
+    origin and live rows).  Returns (B, T, Hq, hd) float32 with padded rows
+    zeroed.
     """
     on_tpu = jax.default_backend() == "tpu"
     if on_tpu or _FORCED:
         B, T, Hq, hd = q.shape
-        Hkv = k_cache.shape[2]
+        Hkv = k_cache.shape[1]
         rep = Hq // Hkv
         # fold GQA into the query rows: (B, T, Hkv, rep, hd) ->
         # (B, Hkv, T*rep, hd), row i = chunk token i // rep

@@ -15,20 +15,20 @@ import jax.numpy as jnp
 def ragged_prefill_ref(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                        start: jax.Array, qlen: jax.Array) -> jax.Array:
     """q: (B, T, Hq, hd) — chunk token ``i`` of slot ``b`` sits at absolute
-    position ``start[b] + i``; k,v: (B, Smax, Hkv, hd) caches already
-    holding the chunk's own K/V rows; start, qlen: (B,) int32.  Returns
-    (B, T, Hq, hd) float32 with rows ``i >= qlen[b]`` zeroed."""
+    position ``start[b] + i``; k,v: (B, Hkv, Smax, hd) head-major caches
+    already holding the chunk's own K/V rows; start, qlen: (B,) int32.
+    Returns (B, T, Hq, hd) float32 with rows ``i >= qlen[b]`` zeroed."""
     B, T, Hq, hd = q.shape
-    Smax, Hkv = k_cache.shape[1], k_cache.shape[2]
+    Hkv, Smax = k_cache.shape[1], k_cache.shape[2]
     rep = Hq // Hkv
     qr = q.reshape(B, T, Hkv, rep, hd)
-    s = jnp.einsum("btgrh,bsgh->btgrs", qr, k_cache,
+    s = jnp.einsum("btgrh,bgsh->btgrs", qr, k_cache,
                    preferred_element_type=jnp.float32) / math.sqrt(hd)
     qpos = start[:, None] + jnp.arange(T)[None, :]            # (B, T)
     causal = jnp.arange(Smax)[None, None, :] <= qpos[:, :, None]
     s = jnp.where(causal[:, :, None, None, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("btgrs,bsgh->btgrh", p.astype(v_cache.dtype), v_cache,
+    out = jnp.einsum("btgrs,bgsh->btgrh", p.astype(v_cache.dtype), v_cache,
                      preferred_element_type=jnp.float32)
     out = out.reshape(B, T, Hq, hd)
     valid = jnp.arange(T)[None, :] < qlen[:, None]            # (B, T)

@@ -12,11 +12,8 @@ import jax
 
 
 def _mk(shape: tuple[int, ...], axes: tuple[str, ...]):
-    # axis_types/AxisType only exist on newer jax; Auto is the default there
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -116,4 +116,6 @@ def run(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

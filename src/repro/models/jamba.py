@@ -227,7 +227,7 @@ def cache_spec(cfg: ModelConfig, batch: int, max_seq: int):
     nh, hp, ds = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
     conv_dim = cfg.d_inner + 2 * ds
     cdt = jnp.dtype(cfg.compute_dtype)
-    kv = (nb, batch, max_seq, cfg.n_kv_heads, cfg.hd)
+    kv = (nb, batch, cfg.n_kv_heads, max_seq, cfg.hd)   # head-major
     return {
         "k": jax.ShapeDtypeStruct(kv, cdt),
         "v": jax.ShapeDtypeStruct(kv, cdt),
@@ -244,8 +244,8 @@ def cache_spec(cfg: ModelConfig, batch: int, max_seq: int):
 
 def cache_logical_axes(cfg: ModelConfig):
     return {
-        "k": (None, "batch", "seq_mp", None, None),
-        "v": (None, "batch", "seq_mp", None, None),
+        "k": (None, "batch", None, "seq_mp", None),
+        "v": (None, "batch", None, "seq_mp", None),
         "ssm_moe": (None, None, "batch", None, None, None),
         "conv_moe": (None, None, "batch", None, "ff"),
         "ssm_dense": (None, None, "batch", None, None, None),
@@ -255,5 +255,5 @@ def cache_logical_axes(cfg: ModelConfig):
 
 def cache_seq_axes(cfg: ModelConfig):
     # only the attention KV grows with position; SSM/conv state is O(1)
-    return {"k": 2, "v": 2, "ssm_moe": None, "conv_moe": None,
+    return {"k": 3, "v": 3, "ssm_moe": None, "conv_moe": None,
             "ssm_dense": None, "conv_dense": None}

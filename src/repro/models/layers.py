@@ -289,10 +289,10 @@ def decode_attention(cfg: ModelConfig, q: jax.Array, k_cache: jax.Array,
                      v_cache: jax.Array, pos) -> jax.Array:
     """One-token attention against a (possibly seq-sharded) KV cache.
 
-    q: (B, 1, Hq, hd); caches: (B, Smax, Hkv, hd) constrained to shard Smax
-    over the `model` axis — the softmax max/sum reductions become psums over
-    the model axis, i.e. flash-decode's partial-softmax combine, inserted by
-    SPMD partitioning.  ``pos`` is a scalar (shared position), a (B,)
+    q: (B, 1, Hq, hd); caches: head-major (B, Hkv, Smax, hd) constrained to
+    shard Smax over the `model` axis — the softmax max/sum reductions become
+    psums over the model axis, i.e. flash-decode's partial-softmax combine,
+    inserted by SPMD partitioning.  ``pos`` is a scalar (shared position), a (B,)
     vector, or a (B, 1) per-slot position column (ragged batch: each slot
     masks independently).
 
@@ -303,8 +303,8 @@ def decode_attention(cfg: ModelConfig, q: jax.Array, k_cache: jax.Array,
     function always computed — keeps the single-device path byte-stable.
     """
     B, _, Hq, hd = q.shape
-    k_cache = constrain(k_cache, "batch", "seq_mp", None, None)
-    v_cache = constrain(v_cache, "batch", "seq_mp", None, None)
+    k_cache = constrain(k_cache, "batch", None, "seq_mp", None)
+    v_cache = constrain(v_cache, "batch", None, "seq_mp", None)
     pos_vec = position_vector(pos, B)
     out = ragged_decode_attention(q.reshape(B, Hq, hd), k_cache, v_cache,
                                   pos_vec)
@@ -318,9 +318,9 @@ def prefill_chunk_attention(cfg: ModelConfig, q: jax.Array,
     prefill analogue of :func:`decode_attention`).
 
     q: (B, T, Hq, hd) — chunk token ``i`` of slot ``b`` sits at absolute
-    position ``start[b] + i``; caches: (B, Smax, Hkv, hd), already holding
-    the chunk's own K/V rows; ``qlen``: live rows per slot (padded rows
-    return zeros).  The score/softmax math lives in
+    position ``start[b] + i``; caches: head-major (B, Hkv, Smax, hd), already
+    holding the chunk's own K/V rows; ``qlen``: live rows per slot (padded
+    rows return zeros).  The score/softmax math lives in
     :mod:`repro.kernels.ragged_prefill` behind the same A/B guard as decode
     attention: the Pallas kernel (TPU, or interpret mode under
     ``ragged_prefill.force_pallas``) streams K/V blocks only up to each
@@ -328,8 +328,8 @@ def prefill_chunk_attention(cfg: ModelConfig, q: jax.Array,
     the single-device path byte-stable.
     """
     B, T, Hq, _ = q.shape
-    k_cache = constrain(k_cache, "batch", "seq_mp", None, None)
-    v_cache = constrain(v_cache, "batch", "seq_mp", None, None)
+    k_cache = constrain(k_cache, "batch", None, "seq_mp", None)
+    v_cache = constrain(v_cache, "batch", None, "seq_mp", None)
     out = ragged_prefill_attention(q, k_cache, v_cache, start, qlen)
     return out.reshape(B, T, Hq * q.shape[-1]).astype(q.dtype)
 
@@ -337,17 +337,18 @@ def prefill_chunk_attention(cfg: ModelConfig, q: jax.Array,
 @dataclasses.dataclass
 class AttnOut:
     x: jax.Array
-    k: jax.Array | None = None     # new K/V for cache insertion
-    v: jax.Array | None = None
+    k: jax.Array | None = None     # new K/V for cache insertion, head-major
+    v: jax.Array | None = None     # (B, Hkv, S, hd) like every KV cache
 
 
 def attention_decode_inplace(cfg: ModelConfig, p: Params, x: jax.Array,
                              kfull: jax.Array, vfull: jax.Array,
                              layer_idx, pos, rope: bool = True):
-    """One-token attention updating the STACKED (L, B, Smax, Hkv, hd) caches
-    in place: writes only the (B, 1, Hkv, hd) token slice (a scan carrying
-    the full cache aliases these updates, unlike ys-stacking which rewrites
-    a full layer slice per step — see EXPERIMENTS.md §Perf decode entry).
+    """One-token attention updating the STACKED head-major
+    (L, B, Hkv, Smax, hd) caches in place: writes only the (B, Hkv, hd)
+    token slice (a scan carrying the full cache aliases these updates,
+    unlike ys-stacking which rewrites a full layer slice per step — see
+    EXPERIMENTS.md §Perf decode entry).
 
     ``pos`` may be a scalar or a per-slot ``(B,)`` vector (ragged continuous
     batching: every slot decodes at its own position)."""
@@ -358,9 +359,9 @@ def attention_decode_inplace(cfg: ModelConfig, p: Params, x: jax.Array,
     positions = pos_vec[:, None]
     q, k, v = _qkv(cfg, p, x, x, positions, positions, rope)
     batch_ix = jnp.arange(B)
-    kfull = kfull.at[layer_idx, batch_ix, pos_vec].set(
+    kfull = kfull.at[layer_idx, batch_ix, :, pos_vec].set(
         k[:, 0].astype(kfull.dtype))
-    vfull = vfull.at[layer_idx, batch_ix, pos_vec].set(
+    vfull = vfull.at[layer_idx, batch_ix, :, pos_vec].set(
         v[:, 0].astype(vfull.dtype))
     kc = jax.lax.dynamic_index_in_dim(kfull, layer_idx, 0, keepdims=False)
     vc = jax.lax.dynamic_index_in_dim(vfull, layer_idx, 0, keepdims=False)
@@ -375,8 +376,8 @@ def attention_prefill_chunk_inplace(cfg: ModelConfig, p: Params,
                                     start: jax.Array, qlen: jax.Array,
                                     positions: jax.Array,
                                     rope: bool = True):
-    """Chunk-of-tokens attention updating the STACKED (L, B, Smax, Hkv, hd)
-    caches in place — the chunked-prefill analogue of
+    """Chunk-of-tokens attention updating the STACKED head-major
+    (L, B, Hkv, Smax, hd) caches in place — the chunked-prefill analogue of
     :func:`attention_decode_inplace`.  ``x``: (B, T, D) chunk activations;
     ``positions``: (B, T) absolute positions (``start[:, None] +
     arange(T)``); padded rows (``i >= qlen[b]``) scatter out of bounds and
@@ -385,13 +386,13 @@ def attention_prefill_chunk_inplace(cfg: ModelConfig, p: Params,
     x = x.astype(cdt)
     B, T, _ = x.shape
     q, k, v = _qkv(cfg, p, x, x, positions, positions, rope)
-    Smax = kfull.shape[2]
+    Smax = kfull.shape[3]
     batch_ix = jnp.arange(B)[:, None]
     live = jnp.arange(T)[None, :] < qlen[:, None]
     safe_pos = jnp.where(live, positions, Smax)       # OOB rows are dropped
-    kfull = kfull.at[layer_idx, batch_ix, safe_pos].set(
+    kfull = kfull.at[layer_idx, batch_ix, :, safe_pos].set(
         k.astype(kfull.dtype), mode="drop")
-    vfull = vfull.at[layer_idx, batch_ix, safe_pos].set(
+    vfull = vfull.at[layer_idx, batch_ix, :, safe_pos].set(
         v.astype(vfull.dtype), mode="drop")
     kc = jax.lax.dynamic_index_in_dim(kfull, layer_idx, 0, keepdims=False)
     vc = jax.lax.dynamic_index_in_dim(vfull, layer_idx, 0, keepdims=False)
@@ -422,8 +423,8 @@ def attention_apply(cfg: ModelConfig, p: Params, x: jax.Array, *,
         pos_vec = position_vector(pos, B)
         q, k, v = _qkv(cfg, p, x, x, positions, positions, rope)
         batch_ix = jnp.arange(B)
-        kc = k_cache.astype(cdt).at[batch_ix, pos_vec].set(k[:, 0])
-        vc = v_cache.astype(cdt).at[batch_ix, pos_vec].set(v[:, 0])
+        kc = k_cache.astype(cdt).at[batch_ix, :, pos_vec].set(k[:, 0])
+        vc = v_cache.astype(cdt).at[batch_ix, :, pos_vec].set(v[:, 0])
         out = decode_attention(cfg, q, kc, vc, pos_vec[:, None])
         out = out @ p["wo"].astype(cdt)
         return AttnOut(x=constrain(out, "batch", None, None), k=kc, v=vc)
@@ -432,7 +433,7 @@ def attention_apply(cfg: ModelConfig, p: Params, x: jax.Array, *,
         q, _, _ = _qkv(cfg, p, x, x[:, :1], positions, positions, False)
         out = decode_attention(cfg, q, k_cache.astype(cdt),
                                v_cache.astype(cdt),
-                               jnp.asarray(k_cache.shape[1] - 1))
+                               jnp.asarray(k_cache.shape[2] - 1))
         return AttnOut(x=(out @ p["wo"].astype(cdt)))
     src = x if not cross else kv_src.astype(cdt)
     kv_pos = positions if not cross else jnp.arange(src.shape[1])
@@ -442,7 +443,8 @@ def attention_apply(cfg: ModelConfig, p: Params, x: jax.Array, *,
     v = constrain(v, "batch", None, "kv_heads", None)
     out = blocked_attention(cfg, q, k, v, causal=causal and not cross)
     out = out @ p["wo"].astype(cdt)
-    return AttnOut(x=constrain(out, "batch", None, None), k=k, v=v)
+    return AttnOut(x=constrain(out, "batch", None, None),
+                   k=k.transpose(0, 2, 1, 3), v=v.transpose(0, 2, 1, 3))
 
 
 # ---------------------------------------------------------------------------

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ModelConfig
-from ..distributed.sharding import constrain, current_rules, shard_map_compat
+from ..distributed.sharding import constrain, current_rules
 from . import layers as L
 
 
@@ -207,8 +207,9 @@ def moe_ep(cfg: ModelConfig, p, x) -> jax.Array:
     }
 
     body = partial(_moe_ep_local, cfg, n_cols=n_cols, axis=model_ax)
-    fn = shard_map_compat(lambda pp, xx: body(pp, xx), mesh=mesh,
-                          in_specs=(pspec_p, pspec_x), out_specs=pspec_x)
+    fn = jax.shard_map(lambda pp, xx: body(pp, xx), mesh=mesh,
+                       in_specs=(pspec_p, pspec_x), out_specs=pspec_x,
+                       check_vma=False)
     return fn(p, x)
 
 
@@ -417,7 +418,7 @@ def decode(cfg: ModelConfig, p, token, pos, cache):
 
 def cache_spec(cfg: ModelConfig, batch: int, max_seq: int):
     dt = jnp.dtype(cfg.compute_dtype)
-    kv = (batch, max_seq, cfg.n_kv_heads, cfg.hd)
+    kv = (batch, cfg.n_kv_heads, max_seq, cfg.hd)      # head-major
     if cfg.moe_every == 1:
         shp = (cfg.n_layers, *kv)
         return {"k": jax.ShapeDtypeStruct(shp, dt),
@@ -431,7 +432,7 @@ def cache_spec(cfg: ModelConfig, batch: int, max_seq: int):
 
 
 def cache_logical_axes(cfg: ModelConfig):
-    ax = ("batch", "seq_mp", None, None)
+    ax = ("batch", None, "seq_mp", None)
     if cfg.moe_every == 1:
         return {"k": (None, *ax), "v": (None, *ax)}
     return {"k_dense": (None, None, *ax), "v_dense": (None, None, *ax),
@@ -440,5 +441,5 @@ def cache_logical_axes(cfg: ModelConfig):
 
 def cache_seq_axes(cfg: ModelConfig):
     if cfg.moe_every == 1:
-        return {"k": 2, "v": 2}
-    return {"k_dense": 3, "v_dense": 3, "k_moe": 2, "v_moe": 2}
+        return {"k": 3, "v": 3}
+    return {"k_dense": 4, "v_dense": 4, "k_moe": 3, "v_moe": 3}

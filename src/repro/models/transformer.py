@@ -133,12 +133,12 @@ def prefill(cfg: ModelConfig, p, batch):
     x, (ks, vs) = jax.lax.scan(body, x, p["layers"])
     x = L.apply_norm(p["ln_f"], x, cfg.norm)
     logits = L.lm_head(cfg, p["tok"], x[:, -1:])
-    return logits, {"k": ks, "v": vs}        # (L, B, S, Hkv, hd)
+    return logits, {"k": ks, "v": vs}        # (L, B, Hkv, S, hd)
 
 
 def prefill_chunk(cfg: ModelConfig, p, tokens, cache, start, qlen):
-    """Consume one fixed-size prompt chunk against growing (L, B, Smax,
-    Hkv, hd) caches — the chunked-prefill admission path.  ``tokens``:
+    """Consume one fixed-size prompt chunk against growing head-major
+    (L, B, Hkv, Smax, hd) caches — the chunked-prefill admission path.  ``tokens``:
     (B, T) chunk ids (rows past ``qlen[b]`` are padding); ``start``: (B,)
     absolute position of each slot's first chunk token; ``qlen``: (B,) live
     tokens.  The stacked caches ride the scan carry and take a T-row
@@ -169,7 +169,7 @@ def prefill_chunk(cfg: ModelConfig, p, tokens, cache, start, qlen):
 
 
 def decode(cfg: ModelConfig, p, token, pos, cache):
-    """One decode step against (L, B, Smax, Hkv, hd) caches.  The stacked
+    """One decode step against head-major (L, B, Hkv, Smax, hd) caches.  The stacked
     caches ride the scan carry and are updated in place (token-slice DUS),
     so per-layer traffic is the attention read + a 1-token write.  ``pos``
     is a scalar or a per-slot (B,) vector — ragged batches decode each slot
@@ -194,18 +194,18 @@ def decode(cfg: ModelConfig, p, token, pos, cache):
 
 
 def cache_spec(cfg: ModelConfig, batch: int, max_seq: int):
-    shp = (cfg.n_layers, batch, max_seq, cfg.n_kv_heads, cfg.hd)
+    shp = (cfg.n_layers, batch, cfg.n_kv_heads, max_seq, cfg.hd)
     dt = jnp.dtype(cfg.compute_dtype)
     return {"k": jax.ShapeDtypeStruct(shp, dt),
             "v": jax.ShapeDtypeStruct(shp, dt)}
 
 
 def cache_logical_axes(cfg: ModelConfig):
-    return {"k": (None, "batch", "seq_mp", None, None),
-            "v": (None, "batch", "seq_mp", None, None)}
+    return {"k": (None, "batch", None, "seq_mp", None),
+            "v": (None, "batch", None, "seq_mp", None)}
 
 
 def cache_seq_axes(cfg: ModelConfig):
     """Axis index (in the full cache leaf) that grows with decode position;
     None = fixed-size state.  Used by session extract/insert."""
-    return {"k": 2, "v": 2}
+    return {"k": 3, "v": 3}

@@ -182,25 +182,25 @@ def cache_spec(cfg: ModelConfig, batch: int, max_seq: int):
     nb = cfg.n_layers // cfg.cross_attn_every
     per_self = cfg.cross_attn_every - 1
     cdt = jnp.dtype(cfg.compute_dtype)
-    kv = (cfg.n_kv_heads, cfg.hd)
+    H, hd = cfg.n_kv_heads, cfg.hd          # head-major KV
     return {
-        "k_self": jax.ShapeDtypeStruct((nb, per_self, batch, max_seq, *kv), cdt),
-        "v_self": jax.ShapeDtypeStruct((nb, per_self, batch, max_seq, *kv), cdt),
-        "k_cross": jax.ShapeDtypeStruct((nb, batch, cfg.n_image_tokens, *kv), cdt),
-        "v_cross": jax.ShapeDtypeStruct((nb, batch, cfg.n_image_tokens, *kv), cdt),
+        "k_self": jax.ShapeDtypeStruct((nb, per_self, batch, H, max_seq, hd), cdt),
+        "v_self": jax.ShapeDtypeStruct((nb, per_self, batch, H, max_seq, hd), cdt),
+        "k_cross": jax.ShapeDtypeStruct((nb, batch, H, cfg.n_image_tokens, hd), cdt),
+        "v_cross": jax.ShapeDtypeStruct((nb, batch, H, cfg.n_image_tokens, hd), cdt),
     }
 
 
 def cache_logical_axes(cfg: ModelConfig):
     return {
-        "k_self": (None, None, "batch", "seq_mp", None, None),
-        "v_self": (None, None, "batch", "seq_mp", None, None),
-        "k_cross": (None, "batch", "seq_mp", None, None),
-        "v_cross": (None, "batch", "seq_mp", None, None),
+        "k_self": (None, None, "batch", None, "seq_mp", None),
+        "v_self": (None, None, "batch", None, "seq_mp", None),
+        "k_cross": (None, "batch", None, "seq_mp", None),
+        "v_cross": (None, "batch", None, "seq_mp", None),
     }
 
 
 def cache_seq_axes(cfg: ModelConfig):
     # cross-KV spans the (fixed) image tokens, not the decode position —
     # carried whole in sessions, never trimmed
-    return {"k_self": 3, "v_self": 3, "k_cross": None, "v_cross": None}
+    return {"k_self": 4, "v_self": 4, "k_cross": None, "v_cross": None}

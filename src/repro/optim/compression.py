@@ -23,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..distributed.sharding import shard_map_compat
-
 
 def ef_init(params):
     return jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
@@ -79,5 +77,6 @@ def compressed_allreduce_demo(x: jax.Array, mesh) -> jax.Array:
         npod = qs.shape[0]
         return deq / (npod * ndata)
 
-    fn = shard_map_compat(body, mesh=mesh, in_specs=P(), out_specs=P())
+    fn = jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
+                       check_vma=False)
     return fn(x)

@@ -38,19 +38,20 @@ from ..checkpoint.store import compress, decompress, default_codec
 from ..serve.engine import Request, Session
 
 WIRE_MAGIC = b"RSES"
-# v1: the original layout.  v2 adds one OPTIONAL payload key, "trace"
-# (the request's trace context — see repro.obs.trace), so v1 payloads
-# decode unchanged under the v2 reader: same header struct, same body
-# layout, the new key simply absent.  v3 adds another optional key,
+# v1: the original layout.  v2, v3 and v4 each added one OPTIONAL payload
+# key: "trace" (the request's trace context — see repro.obs.trace),
 # "prefilled" (the session left its source mid-prefill with that many
-# prompt tokens consumed — see Session.prefilled), under the same rule:
-# older payloads decode as complete sessions.  v4 adds the optional
-# "delivery" key — the monotonic ``(origin, rid, epoch)`` delivery id
-# adoption dedups on so a duplicated or retried ship never double-adopts
-# (see Session.delivery) — again purely additive.  Writers always emit
-# the current version; readers accept every version in WIRE_COMPAT.
-WIRE_VERSION = 4
-WIRE_COMPAT = frozenset({1, 2, 3, 4})
+# prompt tokens consumed — see Session.prefilled) and "delivery" (the
+# monotonic ``(origin, rid, epoch)`` id adoption dedups on, so a retried
+# ship never double-adopts — see Session.delivery).  v5 changed the
+# layout: attention KV leaves went from sequence-major ``(..., B, S, Hkv,
+# hd)`` to head-major ``(..., B, Hkv, S, hd)``, the TPU kernels' block
+# layout.  A v1-v4 payload would be inserted as transposed KV (silently,
+# when its length is at most Hkv), so this build reads v5 only and
+# refuses older payloads loudly.  Writers always emit WIRE_VERSION;
+# readers accept exactly WIRE_COMPAT.
+WIRE_VERSION = 5
+WIRE_COMPAT = frozenset({5})
 _CODEC_IDS = {"zlib": 0, "zstd": 1}
 _CODEC_NAMES = {v: k for k, v in _CODEC_IDS.items()}
 # magic(4) + version(1) + codec(1) + crc32(4)
@@ -130,9 +131,8 @@ def wire_header(data: bytes) -> dict:
             f"bad magic {magic!r}: not a session wire payload")
     if version not in WIRE_COMPAT:
         # explicit compat set: the CRC covers only the body, so a corrupted
-        # version byte (e.g. 4 -> 0) must fail HERE, not be decoded under
-        # the wrong layout; v1-v3 stay readable (v2/v3/v4 each only added
-        # an optional key)
+        # version byte (e.g. 5 -> 4) must fail HERE, not be decoded under
+        # the wrong layout
         raise WireFormatError(
             f"unsupported session wire version {version} "
             f"(this build reads {sorted(WIRE_COMPAT)})")
@@ -179,13 +179,14 @@ def decode_session(data: bytes) -> Session:
                               for k, v in r["extras"].items()},
                       out_tokens=list(r["out_tokens"]), done=r["done"],
                       t_first=r["t_first"], t_admit=r["t_admit"])
-        delivery = payload.get("delivery")           # absent pre-v4
+        # optional keys: the writer omits each one that is None
+        delivery = payload.get("delivery")
         return Session(req=req, pos=payload["pos"],
                        cur_token=payload["cur_token"],
                        cache={k: _unpack_array(v)
                               for k, v in payload["cache"].items()},
-                       trace=payload.get("trace"),   # absent on v1 payloads
-                       prefilled=payload.get("prefilled"),  # absent pre-v3
+                       trace=payload.get("trace"),
+                       prefilled=payload.get("prefilled"),
                        delivery=(tuple(delivery) if delivery is not None
                                  else None))
     except WireFormatError:

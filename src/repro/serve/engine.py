@@ -116,6 +116,14 @@ class ServeEngine:
             raise ValueError(f"unknown role {role!r}")
         self.model = model
         self.params = params
+        # the engine lives where its weights do: caches and device-resident
+        # tok/pos go to the device ``params`` are committed to, so replica i
+        # built over ``jax.device_put(params, jax.devices()[i])`` runs on
+        # chip i.  Params sharded over several devices, or none at all,
+        # leave the device unset: allocation keeps JAX's default placement
+        leaves = jax.tree.leaves(params)
+        devices = leaves[0].devices() if leaves else set()
+        self.device = next(iter(devices)) if len(devices) == 1 else None
         self.max_batch = max_batch
         self.max_seq = max_seq
         self.decode_chunk = max(int(decode_chunk), 1)
@@ -306,11 +314,15 @@ class ServeEngine:
     def _free_slots(self) -> list[int]:
         return [i for i, r in enumerate(self.active) if r is None]
 
+    def _zeros_cache(self, batch: int) -> dict:
+        """A zeroed ``(batch, max_seq)`` cache on this engine's device."""
+        spec = self.model.cache_spec(batch, self.max_seq)
+        return jax.tree.map(
+            lambda s: jnp.zeros(s.shape, s.dtype, device=self.device), spec)
+
     def _ensure_cache(self) -> None:
         if self.cache is None:
-            spec = self.model.cache_spec(self.max_batch, self.max_seq)
-            self.cache = jax.tree.map(
-                lambda s: jnp.zeros(s.shape, s.dtype), spec)
+            self.cache = self._zeros_cache(self.max_batch)
 
     def _chunking(self) -> bool:
         """Whether chunked prefill admission is live on this engine."""
@@ -385,10 +397,8 @@ class ServeEngine:
                     break
                 req = self.queue.popleft()
                 req.t_admit = time.perf_counter()
-                spec = self.model.cache_spec(1, self.max_seq)
-                cache = jax.tree.map(
-                    lambda s: jnp.zeros(s.shape, s.dtype), spec)
-                self.prefilling.append(_Prefill(req=req, cache=cache))
+                self.prefilling.append(
+                    _Prefill(req=req, cache=self._zeros_cache(1)))
                 continue
             if not slots and self.on_prefill_complete is None:
                 break                # whole-prompt path needs a slot unless
@@ -397,9 +407,10 @@ class ServeEngine:
             t0 = time.perf_counter()
             req.t_admit = t0
             d = self.scheduler.schedule_prefill(len(req.prompt))
-            batch = {"tokens": jnp.asarray(req.prompt)[None, :]}
+            batch = {"tokens": np.array(req.prompt, np.int32)[None, :]}
             for name, val in req.extras.items():
-                batch[name] = jnp.asarray(val)[None]
+                batch[name] = np.array(val)[None]
+            batch = jax.device_put(batch, self.device)
             logits, cache = self.model.prefill(self.params, batch)
             next_tok = int(jnp.argmax(logits[0, -1]))
             prefill_dur = time.perf_counter() - t0
@@ -435,10 +446,11 @@ class ServeEngine:
         d = self.scheduler.schedule_prefill(qlen)
         chunk = np.zeros((1, C), np.int32)
         chunk[0, :qlen] = prompt[pf.consumed:pf.consumed + qlen]
+        tokens, start, live = jax.device_put(
+            (chunk, np.array([pf.consumed], np.int32),
+             np.array([qlen], np.int32)), self.device)
         logits, pf.cache = self.model.prefill_chunk(
-            self.params, jnp.asarray(chunk), pf.cache,
-            jnp.asarray([pf.consumed], jnp.int32),
-            jnp.asarray([qlen], jnp.int32))
+            self.params, tokens, pf.cache, start, live)
         pf.logits = logits
         pf.consumed += qlen
         done = pf.consumed >= len(prompt)
@@ -592,9 +604,8 @@ class ServeEngine:
             if tid is not None:
                 self.tracer.instant("migrate-in", tid, self.obs_name,
                                     pos=sess.pos, prefilled=sess.prefilled)
-        spec = self.model.cache_spec(1, self.max_seq)
-        cache = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), spec)
-        cache = self.model.insert_session(cache, 0, sess.cache)
+        cache = self.model.insert_session(self._zeros_cache(1), 0,
+                                          sess.cache)
         self.prefilling.append(
             _Prefill(req=sess.req, cache=cache, consumed=sess.prefilled))
 
@@ -689,8 +700,8 @@ class ServeEngine:
             # both paths keep cur_token/pos device-resident between steps;
             # this re-upload runs only after a slot-changing event
             # (admission, finish, export) marked them dirty
-            self._dev_tok = jnp.asarray(self.cur_token)
-            self._dev_pos = jnp.asarray(self.pos)
+            self._dev_tok, self._dev_pos = jax.device_put(
+                (self.cur_token, self.pos), self.device)
             self._dev_dirty = False
         if self.fused:
             k = self.decode_chunk

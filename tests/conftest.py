@@ -24,13 +24,6 @@ def run_subprocess(code: str, devices: int = 8) -> str:
         f'os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={devices}"\n'
         "import sys\n"
         f"sys.path.insert(0, {os.path.join(os.path.dirname(__file__), '..', 'src')!r})\n"
-        # jax < 0.5 compat: AxisType/axis_types don't exist yet; Auto is the
-        # default behaviour there, so accept-and-drop the kwarg
-        "import enum, jax\n"
-        "if not hasattr(jax.sharding, 'AxisType'):\n"
-        "    jax.sharding.AxisType = enum.Enum('AxisType', 'Auto Explicit Manual')\n"
-        "    _mm = jax.make_mesh\n"
-        "    jax.make_mesh = lambda shape, names, axis_types=None, **kw: _mm(shape, names, **kw)\n"
     )
     proc = subprocess.run(
         [sys.executable, "-c", prelude + textwrap.dedent(code)],

@@ -309,7 +309,7 @@ def test_jaxpr_flags_host_callback_and_f64():
     rules = [f.rule for f in jaxpr_findings(jaxpr.jaxpr, "toy")]
     assert rules == ["host-callback"]
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(lambda x: x.astype(jnp.float64) * 2)(
             jnp.ones((2,), jnp.float32))
     rules = [f.rule for f in jaxpr_findings(jaxpr.jaxpr, "toy")]

@@ -124,8 +124,8 @@ def test_ragged_kernel_matches_reference_numerically():
                                        (3, 19, 6, 6, 8, 8)):
         import jax.numpy as jnp
         q = jnp.asarray(rng.normal(size=(B, Hq, hd)), jnp.float32)
-        k = jnp.asarray(rng.normal(size=(B, Smax, Hkv, hd)), jnp.float32)
-        v = jnp.asarray(rng.normal(size=(B, Smax, Hkv, hd)), jnp.float32)
+        k = jnp.asarray(rng.normal(size=(B, Hkv, Smax, hd)), jnp.float32)
+        v = jnp.asarray(rng.normal(size=(B, Hkv, Smax, hd)), jnp.float32)
         pos = jnp.asarray(rng.integers(0, Smax, B), jnp.int32)
         ref = ragged_decode_ref(q, k, v, pos)
         with force_pallas():

@@ -23,8 +23,8 @@ from repro.kernels.ragged_prefill.ref import ragged_prefill_ref
 from repro.models import get_model
 from repro.obs import MetricRegistry, SpanTracer
 from repro.region.router import RegionRouter
-from repro.region.wire import (WIRE_VERSION, decode_session, encode_session,
-                               wire_header)
+from repro.region.wire import (WIRE_VERSION, WireFormatError, decode_session,
+                               encode_session, wire_header)
 from repro.router.gateway import FleetGateway
 from repro.router.router import FleetRouter
 from repro.serve import Request, ServeEngine
@@ -82,8 +82,8 @@ def test_ragged_prefill_kernel_matches_reference(B, Smax, T, Hq, Hkv, hd,
     oracle, including zeroed padding rows past each slot's qlen."""
     rng = np.random.default_rng(11)
     q = jnp.asarray(rng.normal(size=(B, T, Hq, hd)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(B, Smax, Hkv, hd)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(B, Smax, Hkv, hd)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(B, Hkv, Smax, hd)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(B, Hkv, Smax, hd)), jnp.float32)
     start = jnp.asarray(rng.integers(0, Smax - T, B), jnp.int32)
     # mix live, partial, and fully-padded (qlen=0) slots
     qlen = jnp.asarray(([T, max(T - 2, 1), 0, T] * B)[:B], jnp.int32)
@@ -389,10 +389,11 @@ def test_wire_v3_prefilled_roundtrip_and_compat():
     full = Session(req=req, pos=3, cur_token=9,
                    cache={"k": np.ones((2, 3, 4), np.float32)})
     assert decode_session(encode_session(full)).prefilled is None
-    # a v2 header over the same body still decodes (optional-key compat)
+    # a v2 header over the same body is refused: pre-v5 payloads carry
+    # sequence-major KV, and a v5 reader must not guess at their layout
     import struct
     hdr = struct.Struct(">4sBBI")
     magic, ver, codec, crc = hdr.unpack_from(data)
     v2 = hdr.pack(magic, 2, codec, crc) + data[hdr.size:]
-    assert wire_header(v2)["version"] == 2
-    assert decode_session(v2).prefilled == 3
+    with pytest.raises(WireFormatError, match="version 2"):
+        wire_header(v2)

@@ -98,8 +98,8 @@ def test_ragged_decode_parity(B, Hq, Hkv, Smax, hd, bk):
     """Pallas ragged decode attention (interpret mode, via force_pallas)
     matches the jnp oracle at mixed per-slot positions."""
     q = jnp.asarray(RNG.standard_normal((B, Hq, hd)), jnp.float32)
-    k = jnp.asarray(RNG.standard_normal((B, Smax, Hkv, hd)), jnp.float32)
-    v = jnp.asarray(RNG.standard_normal((B, Smax, Hkv, hd)), jnp.float32)
+    k = jnp.asarray(RNG.standard_normal((B, Hkv, Smax, hd)), jnp.float32)
+    v = jnp.asarray(RNG.standard_normal((B, Hkv, Smax, hd)), jnp.float32)
     pos = jnp.asarray(RNG.integers(0, Smax, (B,)), jnp.int32)
     with ragged_decode_ops.force_pallas():
         got = ragged_decode_ops.ragged_decode_attention(q, k, v, pos,
@@ -117,8 +117,8 @@ def test_ragged_prefill_parity(B, T, Hq, Hkv, Smax, hd, bk):
     """Pallas chunked ragged prefill attention (interpret mode) matches the
     jnp oracle with per-slot chunk origins and ragged live lengths."""
     q = jnp.asarray(RNG.standard_normal((B, T, Hq, hd)), jnp.float32)
-    k = jnp.asarray(RNG.standard_normal((B, Smax, Hkv, hd)), jnp.float32)
-    v = jnp.asarray(RNG.standard_normal((B, Smax, Hkv, hd)), jnp.float32)
+    k = jnp.asarray(RNG.standard_normal((B, Hkv, Smax, hd)), jnp.float32)
+    v = jnp.asarray(RNG.standard_normal((B, Hkv, Smax, hd)), jnp.float32)
     start = jnp.asarray(RNG.integers(0, Smax - T, (B,)), jnp.int32)
     qlen = jnp.asarray(RNG.integers(1, T + 1, (B,)), jnp.int32)
     with ragged_prefill_ops.force_pallas():

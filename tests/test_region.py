@@ -110,23 +110,41 @@ def test_wire_rejects_corrupt_and_foreign_payloads():
 
 
 def test_wire_v1_payload_still_decodes():
-    """Backward compat: v2/v3/v4 each only added an optional payload key,
-    so a v1 payload — same layout, version byte 1, no "trace"/"prefilled"/
-    "delivery" keys — must decode unchanged (trace=None, prefilled=None,
-    delivery=None), while versions outside WIRE_COMPAT raise."""
-    assert WIRE_VERSION == 4 and WIRE_COMPAT == frozenset({1, 2, 3, 4})
+    """v1-v4 payloads carry sequence-major KV; v5 moved it to head-major,
+    so an older payload no longer decodes: it is refused at the header,
+    never inserted as transposed KV.  Optional keys stay optional: a v5
+    payload without "trace"/"prefilled"/"delivery" decodes with None."""
+    assert WIRE_VERSION == 5 and WIRE_COMPAT == frozenset({5})
     sess = _synthetic_session()
     assert sess.trace is None
-    data = bytearray(encode_session(sess))      # v4 writer, no optional
-    data[4] = 1                                 # keys: byte-identical to a
-    out = decode_session(bytes(data))           # v1 writer's output
-    assert wire_header(bytes(data))["version"] == 1
-    assert out.pos == sess.pos and out.trace is None
-    assert out.prefilled is None
+    data = encode_session(sess)
+    out = decode_session(data)
+    assert out.trace is None and out.prefilled is None
     assert out.delivery is None
-    assert out.req.out_tokens == sess.req.out_tokens
-    for k in sess.cache:
-        assert np.array_equal(out.cache[k], sess.cache[k])
+    for old in (1, 2, 3, 4):
+        buf = bytearray(data)
+        buf[4] = old
+        with pytest.raises(WireFormatError, match="version"):
+            decode_session(bytes(buf))
+
+
+@pytest.mark.parametrize("version", [1, 4])
+def test_wire_pre_v5_session_refused_by_engine(version):
+    """A live session shipped by a pre-v5 (sequence-major KV) writer is
+    refused by the importing engine, which keeps nothing of it."""
+    cfg = get_config("smollm-135m", reduced=True)
+    m = get_model(cfg)
+    params, _ = m.init(jax.random.PRNGKey(0))
+    prompt = np.random.default_rng(2).integers(0, cfg.vocab, 3)
+    a = ServeEngine(m, params, max_batch=2, max_seq=48)
+    b = ServeEngine(m, params, max_batch=2, max_seq=48)
+    a.submit(Request(rid=1, prompt=prompt, max_new=8))
+    a.step()
+    old = bytearray(a.export_session_wire(1))
+    old[4] = version                 # the CRC covers only the body
+    with pytest.raises(WireFormatError, match="version"):
+        b.import_session_wire(bytes(old))
+    assert not b.sessions_in and b.active_count() == 0
 
 
 def test_wire_carries_trace_context():

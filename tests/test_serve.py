@@ -123,3 +123,42 @@ def test_engine_queueing_more_requests_than_slots():
     assert all(len(r.out_tokens) >= 4 for r in reqs)
     # the PTT saw both prefill (critical) and decode (non-critical) updates
     assert engine.scheduler.ptt.updates > len(reqs)
+
+
+def test_engine_lives_on_its_params_device(subproc):
+    """Replica placement: an engine whose params are committed to device i
+    keeps its batch cache, chunked-prefill caches and tok/pos on device i,
+    and serves the same tokens as an engine on the default device."""
+    out = subproc("""
+        import jax, numpy as np
+        from repro.configs import get_config
+        from repro.models import get_model
+        from repro.serve import Request, ServeEngine
+        cfg = get_config("smollm-135m", reduced=True)
+        m = get_model(cfg)
+        p, _ = m.init(jax.random.PRNGKey(0))
+        dev = jax.devices()[1]
+        rng = np.random.default_rng(0)
+        prompts = [rng.integers(0, cfg.vocab, n) for n in (5, 11)]
+        streams = []
+        for params in (p, jax.device_put(p, dev)):
+            e = ServeEngine(m, params, max_batch=2, max_seq=32,
+                            decode_chunk=2, prefill_chunk_tokens=4)
+            reqs = [Request(rid=i, prompt=pr, max_new=5)
+                    for i, pr in enumerate(prompts)]
+            for r in reqs:
+                e.submit(r)
+            homes = set()
+            for _ in range(3):       # rid 1 admitted, then rid 0 decoding
+                e.step()
+                homes |= {d for x in jax.tree.leaves(
+                    (e.cache, [pf.cache for pf in e.prefilling],
+                     e._dev_tok, e._dev_pos)) for d in x.devices()}
+            assert e.cache is not None and e.prefilling
+            e.run_until_drained(max_steps=100)
+            streams.append([r.out_tokens for r in reqs])
+        assert homes == {dev}, homes
+        assert streams[0] == streams[1], streams
+        print("OK")
+    """, devices=2)
+    assert "OK" in out
