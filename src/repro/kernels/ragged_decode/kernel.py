@@ -128,4 +128,5 @@ def ragged_decode_pallas(q: jax.Array, k_cache: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rep, hd), jnp.float32),
         interpret=interpret,
+        name="ragged_decode",
     )(pos.astype(jnp.int32), q, k_cache, v_cache)

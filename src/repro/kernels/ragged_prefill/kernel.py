@@ -145,4 +145,5 @@ def ragged_prefill_pallas(q: jax.Array, k_cache: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, tr, hd), jnp.float32),
         interpret=interpret,
+        name="ragged_prefill",
     )(start.astype(jnp.int32), qlen.astype(jnp.int32), q, k_cache, v_cache)

@@ -94,9 +94,10 @@ def _fused_decode(cfg: ModelConfig, mod) -> Callable:
             logits, c = mod.decode(cfg, params, tok, p, c)
             nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
             return (nxt[:, None], p + 1, c), nxt
-        (token, pos, cache), toks = jax.lax.scan(
-            step, (token, pos, cache), None, length=k)
-        return jnp.moveaxis(toks, 0, 1), token, pos, cache
+        with jax.named_scope("decode_fused"):
+            (token, pos, cache), toks = jax.lax.scan(
+                step, (token, pos, cache), None, length=k)
+            return jnp.moveaxis(toks, 0, 1), token, pos, cache
 
     return jax.jit(fused, static_argnums=4, donate_argnums=3)
 
@@ -111,7 +112,8 @@ def _chunked_prefill(cfg: ModelConfig, mod) -> Callable | None:
         return None
 
     def chunk(params, tokens, cache, start, qlen):
-        return mod.prefill_chunk(cfg, params, tokens, cache, start, qlen)
+        with jax.named_scope("prefill_chunk"):
+            return mod.prefill_chunk(cfg, params, tokens, cache, start, qlen)
 
     return jax.jit(chunk, donate_argnums=2)
 
@@ -126,8 +128,9 @@ def get_model(cfg: ModelConfig) -> Model:
                                         mod.cache_seq_axes(cfg))
 
     def insert_session(cache, slot: int, session):
-        return sessions.insert_session(cache, slot, session,
-                                       mod.cache_logical_axes(cfg))
+        with jax.named_scope("insert_session"):
+            return sessions.insert_session(cache, slot, session,
+                                           mod.cache_logical_axes(cfg))
 
     return Model(cfg=cfg, init=bind(mod.init), forward=bind(mod.forward),
                  prefill=bind(mod.prefill), decode=bind(mod.decode),

@@ -28,8 +28,9 @@ and the placements acted on them — visible:
   (:func:`dump_jsonl`, :func:`load_jsonl`, :func:`replay`).
 
 All of it is opt-in: every instrumented class defaults to the null
-tracer / no registry / no log, and the null-path decode overhead is
-benchmarked (``benchmarks/obs_overhead.py``) and CI-bounded.
+tracer / no registry / no log, whose hot-path cost is one ``enabled``
+check or one no-op call per span.  The tracer's context spans also land
+in a running profiler session, on the device trace's clock.
 
 ``CANONICAL_STATS`` names the counter keys every scale's ``stats()``
 facade agrees on (old per-scale keys remain as aliases for one release).
@@ -43,7 +44,7 @@ from .replay import (ReplayReport, dump_jsonl, load_jsonl, parse_cost,
 from .server import ObsServer
 from .slo import Alert, Objective, SLOMonitor
 from .timeseries import TimeSeriesStore
-from .trace import NULL_TRACER, NullTracer, SpanTracer
+from .trace import NULL_TRACER, TRACK_SCOPE, NullTracer, SpanTracer
 
 #: Counter keys shared by ServeEngine.stats(), FleetGateway.stats(), and
 #: RegionGateway.stats() — the unified naming the consistency test pins.
@@ -54,7 +55,7 @@ __all__ = [
     "BYTE_BUCKETS", "LATENCY_BUCKETS", "CANONICAL_STATS",
     "Counter", "Gauge", "Histogram", "MetricRegistry",
     "DecisionLog", "DecisionRecord",
-    "NULL_TRACER", "NullTracer", "SpanTracer",
+    "NULL_TRACER", "NullTracer", "SpanTracer", "TRACK_SCOPE",
     "TimeSeriesStore",
     "Alert", "Objective", "SLOMonitor",
     "ObsServer",

@@ -53,8 +53,7 @@ class TimeSeriesStore:
     """Ring-buffered samples of every series in one registry.
 
     ``cap`` bounds each series' ring; :meth:`sample` is O(live series)
-    and allocation-light (one tuple per series per sample) — priced by
-    ``benchmarks/obs_overhead.py``'s sampled arm, CI-bounded.
+    and allocation-light (one tuple per series per sample).
     """
 
     def __init__(self, registry: MetricRegistry, cap: int = 2048):

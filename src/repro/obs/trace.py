@@ -21,10 +21,23 @@ format Perfetto / ``chrome://tracing`` load directly: traces map to
 processes, tracks to threads, spans to complete ``X`` events and instants
 to ``i`` events, with ``M`` metadata naming both.
 
+A context span (:meth:`SpanTracer.span`) has a second sink: for its
+duration it also holds a ``jax.profiler.TraceAnnotation`` of the same
+name, with the track and the span's args as the annotation's args.  While
+a profiler session runs, every context span therefore sits in the
+profiler's trace, on the clock the device planes share, so device idle
+time can be put against the host work around it.  With no session running
+the annotation is inert (about a microsecond per span).
+
+Events that belong to a track rather than to a request (an engine step, a
+gateway pump) pass :data:`TRACK_SCOPE` as their ``trace``, or open as
+:meth:`SpanTracer.track_span`: they are kept under any ``sample_rate``,
+where ``None`` means a sampled-out request.
+
 The default everywhere is :data:`NULL_TRACER`: a no-op whose ``enabled``
 flag lets hot paths skip even argument construction — the decode loop pays
-one attribute check per chunk (benchmarked in
-``benchmarks/obs_overhead.py``, CI-bounded).
+one attribute check, or one no-op :meth:`~NullTracer.track_span` call, per
+span.
 """
 
 from __future__ import annotations
@@ -34,6 +47,22 @@ import json
 import time
 from collections import deque
 from typing import Callable
+
+#: ``trace`` of an event that belongs to its track, not to a request: it is
+#: filed on the tracer-level timeline and never sampled out.
+TRACK_SCOPE = object()
+
+_annotation = None
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use so the
+    tracer stays importable without JAX."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
 
 
 class NullTracer:
@@ -60,7 +89,10 @@ class NullTracer:
         pass
 
     def span(self, name, trace=None, track=None, **args):
-        return contextlib.nullcontext()
+        return contextlib.nullcontext(args)
+
+    def track_span(self, name, track=None, **args):
+        return contextlib.nullcontext(args)
 
 
 #: Shared no-op default — identity-compared by gateways when deciding
@@ -80,10 +112,12 @@ class SpanTracer:
     ``None`` for sampled-out rids (the decision is sticky per rid), and
     request-bound recording calls whose ``trace`` is ``None`` are dropped
     — instrumented code can keep passing ``trace_for``'s result straight
-    through without its own guard.  Two invariants make sampling safe at
-    production rates: (a) ``sample_rate=1`` (the default) is
-    behavior-identical to the unsampled tracer — ``trace=None`` events
-    keep falling back to the tracer-level timeline; (b) :meth:`adopt`
+    through without its own guard.  Track-scoped events
+    (``trace=TRACK_SCOPE``) are kept at every rate.  Two invariants make
+    sampling safe at production rates: (a) ``sample_rate=1`` (the
+    default) is behavior-identical to the unsampled tracer —
+    ``trace=None`` events keep falling back to the tracer-level timeline;
+    (b) :meth:`adopt`
     force-binds regardless of the local sampling decision, so a sampled
     request that migrates in from another host keeps its full
     cross-boundary timeline — the origin's sampling verdict travels with
@@ -140,46 +174,70 @@ class SpanTracer:
         self._bind[rid] = trace_id
 
     # -- recording ---------------------------------------------------------
-    def _dropped(self, trace) -> bool:
-        # a None trace under sampling is a sampled-out request's event;
-        # under sample_rate=1 it is the legacy "tracer-level timeline"
-        return trace is None and self.sample_rate > 1
+    def _filed(self, trace):
+        """The trace an event is filed under, or ``None`` to drop it.  A
+        ``None`` trace under sampling is a sampled-out request's event;
+        under ``sample_rate=1`` it is the legacy "tracer-level timeline",
+        where track-scoped events always go."""
+        if trace is TRACK_SCOPE:
+            return self.name
+        if trace is None:
+            return None if self.sample_rate > 1 else self.name
+        return trace
 
     def instant(self, name: str, trace: str | None = None,
                 track: str | None = None, **args) -> None:
         """A point event (admit/shed/quarantine/...)."""
-        if self._dropped(trace):
+        trace = self._filed(trace)
+        if trace is None:
             return
         self.events.append({"name": name, "ph": "i", "ts": self.clock(),
-                            "trace": trace or self.name,
-                            "track": track or self.name, "args": args,
-                            "tick": self.tick})
+                            "trace": trace, "track": track or self.name,
+                            "args": args, "tick": self.tick})
 
     def complete(self, name: str, trace: str | None = None,
                  track: str | None = None, *, ts: float, dur: float,
                  **args) -> None:
         """A span recorded after the fact (caller measured ``ts``/``dur``
         itself — the engine's decode chunk, a WAN ship)."""
-        if self._dropped(trace):
+        trace = self._filed(trace)
+        if trace is None:
             return
         self.events.append({"name": name, "ph": "X", "ts": ts,
-                            "dur": max(dur, 0.0),
-                            "trace": trace or self.name,
+                            "dur": max(dur, 0.0), "trace": trace,
                             "track": track or self.name, "args": args})
 
     @contextlib.contextmanager
     def span(self, name: str, trace: str | None = None,
              track: str | None = None, **args):
-        """Context-manager span: records one complete event on exit."""
-        if self._dropped(trace):
-            yield
+        """Context-manager span: records one complete event on exit and,
+        for its duration, holds a profiler ``TraceAnnotation`` of the same
+        name whose args are ``track`` and ``args``.  The body receives the
+        span's args dict; keys it adds are recorded in both sinks (a count
+        known only once the work is done)."""
+        if self._filed(trace) is None:
+            yield args
             return
+        track = track or self.name
+        n_args = len(args)
+        ann = _trace_annotation()(name, track=track, **args)
         t0 = self.clock()
+        ann.__enter__()
         try:
-            yield
+            yield args
         finally:
-            self.complete(name, trace, track, ts=t0,
-                          dur=self.clock() - t0, **args)
+            dur = self.clock() - t0
+            if len(args) > n_args:
+                ann.set_metadata(**dict(list(args.items())[n_args:]))
+            ann.__exit__(None, None, None)
+            self.complete(name, trace, track, ts=t0, dur=dur, **args)
+
+    def track_span(self, name: str, track: str | None = None, **args):
+        """:meth:`span` of ``track``'s own work (an engine's step phase, a
+        gateway's pump), filed under :data:`TRACK_SCOPE`.  On
+        :class:`NullTracer` it is a no-op that still hands the body an
+        args dict, so a hot path calls it without an ``enabled`` guard."""
+        return self.span(name, TRACK_SCOPE, track, **args)
 
     # -- views -------------------------------------------------------------
     def timeline(self, trace_id: str) -> list[dict]:

@@ -814,7 +814,9 @@ class FleetGateway:
                 self.slo.observe("ttft_pumps",
                                  float(self._pump_count - p0))
         # the learning samples span prefill-start -> first token (the
-        # engine stamps t_admit), NOT dispatch -> first token: the
+        # engine stamps t_admit at the whole-prompt prefill or at the
+        # request's first prefill chunk, after any wait behind other
+        # prompts), NOT dispatch -> first token: the
         # engine-queue wait is what QueueAware's backlog term models, so
         # baking it into the TTFT row or the service rate would
         # double-count congestion against busy-but-fast replicas
@@ -1104,15 +1106,27 @@ class FleetGateway:
         """One gateway iteration: apply scheduled faults, check
         heartbeats (recovering crashed replicas' work), retry queued,
         drain quarantined replicas, step every engine, harvest TTFTs.
-        Returns the number of sequences still active fleet-wide."""
+        Returns the number of sequences still active fleet-wide.
+
+        With a tracer attached the call is a ``gateway.pump`` span on the
+        gateway's track holding ``gateway.control`` (faults, heartbeats,
+        duplicates, retries, migrations) and ``gateway.harvest``; each
+        engine's ``engine.step`` falls between the two, on its own
+        track."""
         self._pump_count += 1
         if self.tracer.enabled:
             self.tracer.set_tick(self._pump_count)
-        self._apply_faults()
-        self._check_heartbeats()
-        self._drain_duplicates()
-        self._retry_held()
-        self._migrate_quarantined()
+        with self.tracer.track_span("gateway.pump", self.obs_name,
+                                    tick=self._pump_count):
+            return self._pump()
+
+    def _pump(self) -> int:
+        with self.tracer.track_span("gateway.control", self.obs_name):
+            self._apply_faults()
+            self._check_heartbeats()
+            self._drain_duplicates()
+            self._retry_held()
+            self._migrate_quarantined()
         want_tpot = self.slo is not None and self.slo.wants("tpot")
         active = 0
         for e in self.engines:
@@ -1120,6 +1134,13 @@ class FleetGateway:
             active += a
             if want_tpot and a and e.last_step_latency > 0:
                 self.slo.observe("tpot", e.last_step_latency)
+        with self.tracer.track_span("gateway.harvest", self.obs_name):
+            self._harvest()
+        self._sample_obs()
+        return active
+
+    def _harvest(self) -> None:
+        """Stamp TTFTs and first decodes; stop tracking finished work."""
         in_flight = []
         for t in self.tracked:
             self._harvest_ttft(t)
@@ -1142,8 +1163,6 @@ class FleetGateway:
             else:
                 in_flight.append(t)
         self.tracked = in_flight
-        self._sample_obs()
-        return active
 
     def run_until_drained(self, max_steps: int = 10000) -> None:
         for _ in range(max_steps):

@@ -60,9 +60,11 @@ class Request:
     t_first: float | None = None   # wall time the first token was produced
                                    # (stamped at prefill, so fleet TTFT is
                                    # not inflated by other admissions)
-    t_admit: float | None = None   # wall time the engine started prefill:
-                                   # t_first - t_admit is a pure service
-                                   # sample, free of engine-queue wait
+    t_admit: float | None = None   # wall time the engine started prefill
+                                   # (under chunked admission, the start of
+                                   # the request's first chunk): t_first -
+                                   # t_admit is a pure service sample, free
+                                   # of engine-queue wait
 
 
 @dataclasses.dataclass
@@ -336,7 +338,8 @@ class ServeEngine:
         cache — ``insert_session`` handles both device-side, no host
         round trip)."""
         self._ensure_cache()
-        self.cache = self.model.insert_session(self.cache, slot, cache)
+        with self.tracer.track_span("engine.insert", self.obs_name, slot=slot):
+            self.cache = self.model.insert_session(self.cache, slot, cache)
         self.active[slot] = req
         self.pos[slot] = len(req.prompt)
         self.cur_token[slot, 0] = next_tok
@@ -376,34 +379,41 @@ class ServeEngine:
             return True
         return False
 
-    def _admit(self) -> None:
+    def _admit(self) -> int:
+        """Slot what is ready and start queued prompts; returns how many
+        requests moved (slotted, or started prefilling)."""
         # ragged continuous batching: any free slot takes any queued prompt
         # (chunk-prefilled requests first — their cache is already device
         # resident — then imported sessions, whose prefill was paid on the
         # engine they came from)
         slots = self._free_slots()
+        moved = 0
         while slots and self._prefill_ready:
             req, next_tok, cache = self._prefill_ready.popleft()
             self._slot_in(slots.pop(0), req, next_tok, cache)
+            moved += 1
         while slots and self.sessions_in:
             self._install_session(slots.pop(0), self.sessions_in.popleft())
+            moved += 1
         while self.queue:
             chunkable = self._chunking() and not self.queue[0].extras
             if chunkable:
                 # chunked admission holds no slot: the prompt prefills in
                 # its own cache (one chunk per step, between decode chunks)
-                # and claims a slot — or ships — only when done
+                # and claims a slot — or ships — only when done; its
+                # t_admit is stamped when its first chunk runs
                 if len(self.prefilling) >= self.max_batch:
                     break
                 req = self.queue.popleft()
-                req.t_admit = time.perf_counter()
                 self.prefilling.append(
                     _Prefill(req=req, cache=self._zeros_cache(1)))
+                moved += 1
                 continue
             if not slots and self.on_prefill_complete is None:
                 break                # whole-prompt path needs a slot unless
                                      # every completion hands off
             req = self.queue.popleft()
+            moved += 1
             t0 = time.perf_counter()
             req.t_admit = t0
             d = self.scheduler.schedule_prefill(len(req.prompt))
@@ -426,6 +436,7 @@ class ServeEngine:
             if self._complete_prefill(req, next_tok, cache):
                 continue             # finished at prefill or handed off
             self._slot_in(slots.pop(0), req, next_tok, cache)
+        return moved
 
     def _advance_prefill(self) -> None:
         """Run ONE prefill chunk for the oldest in-flight chunked prefill —
@@ -443,19 +454,23 @@ class ServeEngine:
         t0 = time.perf_counter()
         if pf.t_start is None:
             pf.t_start = t0
-        d = self.scheduler.schedule_prefill(qlen)
-        chunk = np.zeros((1, C), np.int32)
-        chunk[0, :qlen] = prompt[pf.consumed:pf.consumed + qlen]
-        tokens, start, live = jax.device_put(
-            (chunk, np.array([pf.consumed], np.int32),
-             np.array([qlen], np.int32)), self.device)
-        logits, pf.cache = self.model.prefill_chunk(
-            self.params, tokens, pf.cache, start, live)
+        if pf.consumed == 0:
+            pf.req.t_admit = t0      # its service starts with this chunk
+        with self.tracer.track_span("engine.prefill.dispatch", self.obs_name):
+            d = self.scheduler.schedule_prefill(qlen)
+            chunk = np.zeros((1, C), np.int32)
+            chunk[0, :qlen] = prompt[pf.consumed:pf.consumed + qlen]
+            tokens, start, live = jax.device_put(
+                (chunk, np.array([pf.consumed], np.int32),
+                 np.array([qlen], np.int32)), self.device)
+            logits, pf.cache = self.model.prefill_chunk(
+                self.params, tokens, pf.cache, start, live)
         pf.logits = logits
         pf.consumed += qlen
         done = pf.consumed >= len(prompt)
         if done:
-            next_tok = int(jnp.argmax(logits[0, -1]))    # chunk's host sync
+            with self.tracer.track_span("engine.prefill.sync", self.obs_name):
+                next_tok = int(jnp.argmax(logits[0, -1]))  # chunk's host sync
         dur = time.perf_counter() - t0
         self.scheduler.record(d, dur, time.perf_counter())
         self.last_prefill_chunk_latency = dur
@@ -662,7 +677,9 @@ class ServeEngine:
 
     def _install_session(self, slot: int, sess: Session) -> None:
         self._ensure_cache()
-        self.cache = self.model.insert_session(self.cache, slot, sess.cache)
+        with self.tracer.track_span("engine.insert", self.obs_name, slot=slot):
+            self.cache = self.model.insert_session(self.cache, slot,
+                                                   sess.cache)
         self.active[slot] = sess.req
         self.pos[slot] = sess.pos
         self.cur_token[slot, 0] = sess.cur_token
@@ -683,10 +700,40 @@ class ServeEngine:
         flag before the next chunk).  ``last_step_latency`` and the
         ``on_step_latency`` hook report the decode latency **per token**
         (elapsed / chunk), keeping the interference signal comparable
-        across chunk sizes."""
+        across chunk sizes.
+
+        With a tracer attached, the call is an ``engine.step`` span on this
+        engine's track (args: the batch and backlog at its start) holding
+        one span per phase: ``engine.admit``, ``engine.insert``,
+        ``engine.prefill.dispatch``/``.sync``, ``engine.upload``,
+        ``engine.decode.dispatch``/``.sync`` and ``engine.harvest``."""
         if self.crashed:
             return 0                 # a dead process steps nothing
-        self._admit()
+        if self.tracer.enabled:
+            with self.tracer.track_span("engine.step", self.obs_name,
+                                        **self._step_state()):
+                return self._step()
+        return self._step()
+
+    def _step_state(self) -> dict:
+        """The batch and the backlog as a step starts: occupied slots of
+        ``capacity``, prompts queued and prefilling, and the prompt tokens
+        of both not yet prefilled; and the ``device`` (its id) the engine
+        runs on, where it runs on one."""
+        backlog = (sum(len(r.prompt) for r in self.queue)
+                   + sum(len(pf.req.prompt) - pf.consumed
+                         for pf in self.prefilling))
+        state = {"active": self.active_count(), "capacity": self.max_batch,
+                 "queued": len(self.queue),
+                 "prefilling": len(self.prefilling) + len(self._prefill_ready),
+                 "backlog_tokens": backlog}
+        if self.device is not None:
+            state["device"] = self.device.id
+        return state
+
+    def _step(self) -> int:
+        with self.tracer.track_span("engine.admit", self.obs_name) as span:
+            span["admitted"] = self._admit()
         self._advance_prefill()      # one chunk, timed on its own signal
         n_active = self.active_count()
         if self._g_util is not None:
@@ -700,16 +747,20 @@ class ServeEngine:
             # both paths keep cur_token/pos device-resident between steps;
             # this re-upload runs only after a slot-changing event
             # (admission, finish, export) marked them dirty
-            self._dev_tok, self._dev_pos = jax.device_put(
-                (self.cur_token, self.pos), self.device)
+            with self.tracer.track_span("engine.upload", self.obs_name):
+                self._dev_tok, self._dev_pos = jax.device_put(
+                    (self.cur_token, self.pos), self.device)
             self._dev_dirty = False
         if self.fused:
             k = self.decode_chunk
-            toks_dev, self._dev_tok, self._dev_pos, self.cache = (
-                self._decode_fused(self.params, self._dev_tok, self._dev_pos,
-                                   self.cache, k))
+            with self.tracer.track_span("engine.decode.dispatch",
+                                        self.obs_name):
+                toks_dev, self._dev_tok, self._dev_pos, self.cache = (
+                    self._decode_fused(self.params, self._dev_tok,
+                                       self._dev_pos, self.cache, k))
             # the chunk's ONE host sync: a (B, k) block of token ids
-            toks = np.asarray(toks_dev)  # analysis: allow-host-sync(the one sanctioned sync per decode chunk)
+            with self.tracer.track_span("engine.decode.sync", self.obs_name):
+                toks = np.asarray(toks_dev)  # analysis: allow-host-sync(the one sanctioned sync per decode chunk)
         else:
             # legacy per-step path (A/B baseline): undonated decode, but
             # cur_token/pos stay device-resident with the same dirty-resync
@@ -717,13 +768,17 @@ class ServeEngine:
             # (B, 1) token ids cross to host, not the full logits row plus
             # a cur_token re-upload every step
             k = 1
-            logits, self.cache = self._decode(
-                self.params, self._dev_tok, self._dev_pos, self.cache)
-            nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)[:, None]
-            self._dev_tok = nxt
-            self._dev_pos = self._dev_pos + 1
+            with self.tracer.track_span("engine.decode.dispatch",
+                                        self.obs_name):
+                logits, self.cache = self._decode(
+                    self.params, self._dev_tok, self._dev_pos, self.cache)
+                nxt = jnp.argmax(logits[:, 0], axis=-1).astype(
+                    jnp.int32)[:, None]
+                self._dev_tok = nxt
+                self._dev_pos = self._dev_pos + 1
             # the step's ONE host sync: the (B, 1) block of token ids
-            toks = np.asarray(nxt)  # analysis: allow-host-sync(the one sanctioned sync per legacy step)
+            with self.tracer.track_span("engine.decode.sync", self.obs_name):
+                toks = np.asarray(nxt)  # analysis: allow-host-sync(the one sanctioned sync per legacy step)
         decode_elapsed = time.perf_counter() - t0
         self.scheduler.record(d, decode_elapsed, time.perf_counter())
         if self.tracer.enabled:
@@ -735,22 +790,8 @@ class ServeEngine:
                     self.tracer.complete(
                         "decode-chunk", self.tracer.trace_for(req.rid),
                         self.obs_name, ts=t0, dur=decode_elapsed, tokens=k)
-        for i, req in enumerate(self.active):
-            if req is None:
-                continue
-            for j in range(k):
-                req.out_tokens.append(int(toks[i, j]))
-                self.pos[i] += 1
-                self.cur_token[i, 0] = int(toks[i, j])
-                if (len(req.out_tokens) >= req.max_new
-                        or self.pos[i] >= self.max_seq - 1):
-                    req.done = True              # surplus chunk tokens (j+1
-                    self.active[i] = None        # onward) are truncated
-                    self.pos[i] = 0
-                    self.cur_token[i, 0] = 0
-                    self._dev_dirty = True
-                    self._finish(req)
-                    break
+        with self.tracer.track_span("engine.harvest", self.obs_name):
+            self._harvest(toks, k)
         if any(r is None for r in self.active):
             # keep idle slots' device pos pinned at 0: both paths advance
             # every slot's device pos unconditionally, so without this
@@ -767,6 +808,26 @@ class ServeEngine:
         if self.on_step_latency is not None:
             self.on_step_latency(per_token)
         return n_active
+
+    def _harvest(self, toks: np.ndarray, k: int) -> None:
+        """Append each active slot's tokens of the chunk; free the slots
+        that finished (a slot's surplus chunk tokens are truncated)."""
+        for i, req in enumerate(self.active):
+            if req is None:
+                continue
+            for j in range(k):
+                req.out_tokens.append(int(toks[i, j]))
+                self.pos[i] += 1
+                self.cur_token[i, 0] = int(toks[i, j])
+                if (len(req.out_tokens) >= req.max_new
+                        or self.pos[i] >= self.max_seq - 1):
+                    req.done = True              # surplus chunk tokens (j+1
+                    self.active[i] = None        # onward) are truncated
+                    self.pos[i] = 0
+                    self.cur_token[i, 0] = 0
+                    self._dev_dirty = True
+                    self._finish(req)
+                    break
 
     def run_until_drained(self, max_steps: int = 10000) -> None:
         for _ in range(max_steps):

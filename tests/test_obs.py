@@ -24,7 +24,8 @@ from benchmarks.common import percentile  # noqa: E402
 from repro.core.tracetable import (Candidate, Latency, Occupancy,  # noqa: E402
                                    SearchContext, TraceTable)
 from repro.obs import (BYTE_BUCKETS, CANONICAL_STATS, DecisionLog,  # noqa: E402
-                       Histogram, MetricRegistry, NULL_TRACER, SpanTracer)
+                       Histogram, MetricRegistry, NULL_TRACER, SpanTracer,
+                       TRACK_SCOPE)
 
 GOLDEN = os.path.join(os.path.dirname(__file__), "golden")
 
@@ -214,6 +215,69 @@ def test_tracer_event_cap_evicts_oldest():
         tr.instant(f"e{i}")
     assert len(tr.events) == 4
     assert [e["name"] for e in tr.events] == ["e6", "e7", "e8", "e9"]
+
+
+def _profiled(tmp_path, body) -> dict:
+    """Run ``body`` inside a profiler session; the host events of the
+    ``.xplane.pb`` it wrote, by name."""
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    return {e.name: e for plane in ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events}
+
+
+def test_context_spans_reach_the_profiler_trace_with_their_args(tmp_path):
+    tr = SpanTracer("t")
+
+    def body():
+        with tr.span("outer", TRACK_SCOPE, "e0", active=3) as args:
+            with tr.span("inner", tr.trace_for(5), "e0"):
+                pass
+            args["admitted"] = 2
+        tr.complete("after-the-fact", TRACK_SCOPE, "e0", ts=0.0, dur=1.0)
+        tr.instant("point", TRACK_SCOPE, "e0")
+        with NULL_TRACER.span("null-span") as null_args:
+            assert null_args == {}
+
+    events = _profiled(tmp_path, body)
+    outer, inner = events["outer"], events["inner"]
+    assert outer.start_ns <= inner.start_ns
+    assert inner.end_ns <= outer.end_ns
+    assert dict(outer.stats) == {"track": "e0", "active": 3, "admitted": 2}
+    assert dict(inner.stats) == {"track": "e0"}
+    # only context spans have a second sink; the null tracer has none
+    assert not {"after-the-fact", "point", "null-span"} & set(events)
+    by_name = {e["name"]: e for e in tr.events}
+    assert list(by_name) == ["inner", "outer", "after-the-fact", "point"]
+    assert by_name["outer"]["args"] == {"active": 3, "admitted": 2}
+    assert by_name["outer"]["trace"] == "t"
+    assert by_name["inner"]["trace"] == "t/r5"
+
+
+def test_track_scoped_events_survive_sampling():
+    tr = SpanTracer("t", sample_rate=4)
+    assert tr.trace_for(1) is None
+    with tr.span("request", tr.trace_for(1), "e0"):
+        pass
+    with tr.span("step", TRACK_SCOPE, "e0"):
+        pass
+    tr.complete("chunk", TRACK_SCOPE, "e0", ts=0.0, dur=1.0)
+    tr.instant("tick", TRACK_SCOPE)
+    with tr.track_span("phase", "e0", slot=1) as args:
+        args["admitted"] = 2
+    assert [(e["name"], e["trace"], e["track"]) for e in tr.events] == [
+        ("step", "t", "e0"), ("chunk", "t", "e0"), ("tick", "t", "t"),
+        ("phase", "t", "e0")]
+    assert tr.events[-1]["args"] == {"slot": 1, "admitted": 2}
+    with NULL_TRACER.track_span("phase", "e0", slot=1) as args:
+        assert args == {"slot": 1}
 
 
 # ---------------------------------------------------------------------------
