@@ -10,7 +10,8 @@ random weights from ``--seed``, then, in one process:
 
 1. lowers ``decode_fused`` and ``prefill_chunk`` and requires both Pallas
    kernels in them as ``tpu_custom_call``;
-2. runs both kernels on real-width inputs against their jnp oracles;
+2. runs both kernels on real-width inputs against their jnp oracles (and
+   the decode kernel's in-place cache write against the oracle's);
 3. serves 8 requests (prompts of 64..1500 tokens, 32 new tokens each)
    through chunked prefill, and 4 requests of two prompt lengths through
    whole-prompt prefill, on a ``ServeEngine`` with ``max_batch=8``,
@@ -146,12 +147,16 @@ def kernel_errors(cfg, seed: int) -> tuple[float, float]:
     rng = np.random.default_rng(seed)
     B, Hq, Hkv, hd, S = MAX_BATCH, cfg.n_heads, cfg.n_kv_heads, cfg.hd, MAX_SEQ
     bf = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.bfloat16)
-    k, v = bf(B, Hkv, S, hd), bf(B, Hkv, S, hd)
+    kc, vc = bf(2, B, Hkv, hd, S), bf(2, B, Hkv, hd, S)   # two stacked layers
+    kn, vn = bf(B, Hkv, hd), bf(B, Hkv, hd)
     pos = jnp.asarray(rng.integers(0, S, B), jnp.int32)
     q = bf(B, Hq, hd)
-    got = jax.jit(ragged_decode_attention)(q, k, v, pos)
-    ref = jax.jit(ragged_decode_ref)(q, k, v, pos)
+    got, gk, gv = jax.jit(ragged_decode_attention)(q, kc, vc, kn, vn, pos, 1)
+    ref, rk, rv = jax.jit(ragged_decode_ref)(q, kc, vc, kn, vn, pos, 1)
     dec_err = float(jnp.max(jnp.abs(got - ref)))
+    require(bool(jnp.all(gk == rk)) and bool(jnp.all(gv == rv)),
+            "ragged_decode wrote the cache unlike the oracle")
+    k, v = (c[1].swapaxes(-1, -2) for c in (rk, rv))   # (B, Hkv, S, hd)
     T = PREFILL_CHUNK
     q = bf(B, T, Hq, hd)
     start = jnp.asarray(rng.integers(0, S - T, B), jnp.int32)
@@ -227,11 +232,11 @@ def session_kv_errors(cfg, model, params, reqs, ref_len: int):
             toks[0, :s.pos] = seq[:s.pos]           # causal: padding after
             ref = jax.device_get(kv_of(params, jnp.asarray(toks)))
             for name in ("k", "v"):
-                got = s.cache[name].astype(np.float32)     # (L, 1, Hkv, pos, hd)
-                want = ref[name][..., :s.pos, :]
-                err = np.linalg.norm(got - want, axis=(2, 4))
+                got = s.cache[name].astype(np.float32)     # (L, 1, Hkv, hd, pos)
+                want = ref[name][..., :s.pos]
+                err = np.linalg.norm(got - want, axis=(2, 3))
                 worst = max(worst, float(np.max(
-                    err / np.linalg.norm(want, axis=(2, 4)))))
+                    err / np.linalg.norm(want, axis=(2, 3)))))
             n += s.pos
     return worst, n
 

@@ -34,21 +34,25 @@ def force_pallas(enable: bool = True):
 
 
 def ragged_decode_attention(q: jax.Array, k_cache: jax.Array,
-                            v_cache: jax.Array, pos: jax.Array, *,
-                            block_k: int = 128) -> jax.Array:
-    """One-token GQA attention against a ragged batch cache.
+                            v_cache: jax.Array, k_new: jax.Array,
+                            v_new: jax.Array, pos: jax.Array, layer, *,
+                            block_k: int = 128):
+    """One-token GQA attention against a ragged batch cache, writing the
+    token's K/V first.
 
-    q: (B, Hq, hd); k,v: (B, Hkv, Smax, hd) head-major; pos: (B,) int32
-    index of each slot's newest live token (inclusive).  Returns
-    (B, Hq, hd) float32.
+    q: (B, Hq, hd); k,v caches: stacked (L, B, Hkv, hd, Smax); k_new, v_new:
+    (B, Hkv, hd); pos: (B,) int32 index of each slot's newest token, where
+    its K/V is written (a position past the cache writes nothing) and up to
+    which it attends; layer: int32 scalar.  Returns (out (B, Hq, hd)
+    float32, k_cache, v_cache).
     """
     on_tpu = jax.default_backend() == "tpu"
     if on_tpu or _FORCED:
         B, Hq, hd = q.shape
-        Hkv = k_cache.shape[1]
+        Hkv = k_cache.shape[2]
         rep = Hq // Hkv
-        out = ragged_decode_pallas(q.reshape(B, Hkv, rep, hd), k_cache,
-                                   v_cache, pos, block_k=block_k,
-                                   interpret=not on_tpu)
-        return out.reshape(B, Hq, hd)
-    return ragged_decode_ref(q, k_cache, v_cache, pos)
+        out, k_cache, v_cache = ragged_decode_pallas(
+            q.reshape(B, Hkv, rep, hd), k_cache, v_cache, k_new, v_new, pos,
+            layer, block_k=block_k, interpret=not on_tpu)
+        return out.reshape(B, Hq, hd), k_cache, v_cache
+    return ragged_decode_ref(q, k_cache, v_cache, k_new, v_new, pos, layer)

@@ -227,10 +227,10 @@ def cache_spec(cfg: ModelConfig, batch: int, max_seq: int):
     nh, hp, ds = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
     conv_dim = cfg.d_inner + 2 * ds
     cdt = jnp.dtype(cfg.compute_dtype)
-    kv = (nb, batch, cfg.n_kv_heads, max_seq, cfg.hd)   # head-major
+    kv = L.kv_spec(cfg, (nb,), batch, max_seq)
     return {
-        "k": jax.ShapeDtypeStruct(kv, cdt),
-        "v": jax.ShapeDtypeStruct(kv, cdt),
+        "k": kv,
+        "v": kv,
         "ssm_moe": jax.ShapeDtypeStruct((nb, 4, batch, nh, hp, ds),
                                         jnp.float32),
         "conv_moe": jax.ShapeDtypeStruct(
@@ -244,8 +244,8 @@ def cache_spec(cfg: ModelConfig, batch: int, max_seq: int):
 
 def cache_logical_axes(cfg: ModelConfig):
     return {
-        "k": (None, "batch", None, "seq_mp", None),
-        "v": (None, "batch", None, "seq_mp", None),
+        "k": L.kv_logical_axes(1),
+        "v": L.kv_logical_axes(1),
         "ssm_moe": (None, None, "batch", None, None, None),
         "conv_moe": (None, None, "batch", None, "ff"),
         "ssm_dense": (None, None, "batch", None, None, None),
@@ -255,5 +255,5 @@ def cache_logical_axes(cfg: ModelConfig):
 
 def cache_seq_axes(cfg: ModelConfig):
     # only the attention KV grows with position; SSM/conv state is O(1)
-    return {"k": 3, "v": 3, "ssm_moe": None, "conv_moe": None,
-            "ssm_dense": None, "conv_dense": None}
+    return {"k": L.kv_seq_axis(1), "v": L.kv_seq_axis(1), "ssm_moe": None,
+            "conv_moe": None, "ssm_dense": None, "conv_dense": None}

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from ..configs.base import ModelConfig
 from ..distributed.sharding import constrain
 from ..kernels.ragged_decode import ragged_decode_attention
+from ..kernels.ragged_decode.ref import decode_attend_ref
 from ..kernels.ragged_prefill import ragged_prefill_attention
 
 Params = Any   # nested dict pytree
@@ -89,6 +90,41 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
     return out.astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# KV cache layout
+# ---------------------------------------------------------------------------
+# Every attention cache leaf of every family is ``(*lead, B, Hkv, hd, S)``:
+# the sequence axis is minor, which is how the TPU stores it (S on the
+# lanes, hd on the sublanes: no padding of hd 64 to 128 lanes) and what the
+# decode kernel reads and writes in place.  ``lead`` is the family's layer
+# stacking.  cache_spec / cache_logical_axes / cache_seq_axes derive from
+# these three helpers, and so do session extract/insert and the wire.
+
+KV_AXES = ("batch", None, None, "seq_mp")     # B, Hkv, hd, S
+
+
+def kv_spec(cfg: ModelConfig, lead: tuple[int, ...], batch: int,
+            seq: int) -> jax.ShapeDtypeStruct:
+    """One K or V cache leaf: ``(*lead, batch, Hkv, hd, seq)``."""
+    return jax.ShapeDtypeStruct((*lead, batch, cfg.n_kv_heads, cfg.hd, seq),
+                                jnp.dtype(cfg.compute_dtype))
+
+
+def kv_logical_axes(n_lead: int) -> tuple:
+    """Logical axis names of a K or V leaf with ``n_lead`` stacking axes."""
+    return (None,) * n_lead + KV_AXES
+
+
+def kv_seq_axis(n_lead: int) -> int:
+    """The axis of a K or V leaf that grows with the decode position."""
+    return n_lead + KV_AXES.index("seq_mp")
+
+
+def to_kv_layout(x: jax.Array) -> jax.Array:
+    """(B, S, Hkv, hd) projections -> the cache layout (B, Hkv, hd, S)."""
+    return x.transpose(0, 2, 3, 1)
 
 
 # ---------------------------------------------------------------------------
@@ -285,30 +321,33 @@ def _wrapped_causal(cfg, qr, kr, vr, B, Hkv, rep, qb, kb, nq, hd, scale,
     return jnp.concatenate([o_lo, o_hi[::-1]], axis=0)
 
 
-def decode_attention(cfg: ModelConfig, q: jax.Array, k_cache: jax.Array,
-                     v_cache: jax.Array, pos) -> jax.Array:
-    """One-token attention against a (possibly seq-sharded) KV cache.
+def decode_attention(cfg: ModelConfig, q: jax.Array, kfull: jax.Array,
+                     vfull: jax.Array, k: jax.Array, v: jax.Array, pos,
+                     layer) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """One-token attention that writes the token's K/V into a stacked
+    ``(L, B, Hkv, hd, Smax)`` cache (:func:`kv_spec`) at layer ``layer``.
 
-    q: (B, 1, Hq, hd); caches: head-major (B, Hkv, Smax, hd) constrained to
-    shard Smax over the `model` axis — the softmax max/sum reductions become
-    psums over the model axis, i.e. flash-decode's partial-softmax combine,
-    inserted by SPMD partitioning.  ``pos`` is a scalar (shared position), a (B,)
-    vector, or a (B, 1) per-slot position column (ragged batch: each slot
-    masks independently).
+    q: (B, 1, Hq, hd); k, v: (B, 1, Hkv, hd) — the token's new K/V, written
+    at each slot's own position (``pos``: scalar or per-slot (B,); a
+    position past the cache writes nothing) and attended in the same call.
+    The cache is constrained to shard Smax over the `model` axis — the
+    softmax max/sum reductions become psums over the model axis, i.e.
+    flash-decode's partial-softmax combine, inserted by SPMD partitioning.
 
-    The score/softmax math lives in :mod:`repro.kernels.ragged_decode`: the
-    Pallas kernel (TPU, or interpret mode under
-    ``ragged_decode.force_pallas``) reads K/V blocks only up to each slot's
-    position; elsewhere the jnp reference — the exact masked-dense math this
-    function always computed — keeps the single-device path byte-stable.
+    The write and the score/softmax math live in
+    :mod:`repro.kernels.ragged_decode`: the Pallas kernel (TPU, or interpret
+    mode under ``ragged_decode.force_pallas``) reads K/V blocks only up to
+    each slot's position and writes one block per slot, in place;
+    elsewhere the jnp reference does the same write and the masked-dense
+    read.  Returns (out (B, 1, Hq*hd), kfull, vfull).
     """
     B, _, Hq, hd = q.shape
-    k_cache = constrain(k_cache, "batch", None, "seq_mp", None)
-    v_cache = constrain(v_cache, "batch", None, "seq_mp", None)
-    pos_vec = position_vector(pos, B)
-    out = ragged_decode_attention(q.reshape(B, Hq, hd), k_cache, v_cache,
-                                  pos_vec)
-    return out.reshape(B, 1, Hq * hd).astype(q.dtype)
+    kfull = constrain(kfull, *kv_logical_axes(1))
+    vfull = constrain(vfull, *kv_logical_axes(1))
+    out, kfull, vfull = ragged_decode_attention(
+        q.reshape(B, Hq, hd), kfull, vfull, k[:, 0].astype(kfull.dtype),
+        v[:, 0].astype(vfull.dtype), position_vector(pos, B), layer)
+    return out.reshape(B, 1, Hq * hd).astype(q.dtype), kfull, vfull
 
 
 def prefill_chunk_attention(cfg: ModelConfig, q: jax.Array,
@@ -318,18 +357,20 @@ def prefill_chunk_attention(cfg: ModelConfig, q: jax.Array,
     prefill analogue of :func:`decode_attention`).
 
     q: (B, T, Hq, hd) — chunk token ``i`` of slot ``b`` sits at absolute
-    position ``start[b] + i``; caches: head-major (B, Hkv, Smax, hd), already
-    holding the chunk's own K/V rows; ``qlen``: live rows per slot (padded
-    rows return zeros).  The score/softmax math lives in
-    :mod:`repro.kernels.ragged_prefill` behind the same A/B guard as decode
-    attention: the Pallas kernel (TPU, or interpret mode under
+    position ``start[b] + i``; caches: one layer in the cache layout
+    (B, Hkv, hd, Smax), already holding the chunk's own K/V rows; ``qlen``:
+    live rows per slot (padded rows return zeros).  The score/softmax math
+    lives in :mod:`repro.kernels.ragged_prefill`, which reads
+    (B, Hkv, Smax, hd) blocks: the layer is handed to it swapped (one
+    layer's copy per chunk).  Behind the same A/B guard as decode
+    attention, the Pallas kernel (TPU, or interpret mode under
     ``ragged_prefill.force_pallas``) streams K/V blocks only up to each
     slot's ``start + qlen - 1`` horizon; elsewhere the jnp reference keeps
     the single-device path byte-stable.
     """
     B, T, Hq, _ = q.shape
-    k_cache = constrain(k_cache, "batch", None, "seq_mp", None)
-    v_cache = constrain(v_cache, "batch", None, "seq_mp", None)
+    k_cache = constrain(k_cache, *kv_logical_axes(0)).swapaxes(-1, -2)
+    v_cache = constrain(v_cache, *kv_logical_axes(0)).swapaxes(-1, -2)
     out = ragged_prefill_attention(q, k_cache, v_cache, start, qlen)
     return out.reshape(B, T, Hq * q.shape[-1]).astype(q.dtype)
 
@@ -337,18 +378,18 @@ def prefill_chunk_attention(cfg: ModelConfig, q: jax.Array,
 @dataclasses.dataclass
 class AttnOut:
     x: jax.Array
-    k: jax.Array | None = None     # new K/V for cache insertion, head-major
-    v: jax.Array | None = None     # (B, Hkv, S, hd) like every KV cache
+    k: jax.Array | None = None     # new K/V for cache insertion, in the
+    v: jax.Array | None = None     # cache layout (B, Hkv, hd, S) (kv_spec)
 
 
 def attention_decode_inplace(cfg: ModelConfig, p: Params, x: jax.Array,
                              kfull: jax.Array, vfull: jax.Array,
                              layer_idx, pos, rope: bool = True):
-    """One-token attention updating the STACKED head-major
-    (L, B, Hkv, Smax, hd) caches in place: writes only the (B, Hkv, hd)
-    token slice (a scan carrying the full cache aliases these updates,
-    unlike ys-stacking which rewrites a full layer slice per step — see
-    EXPERIMENTS.md §Perf decode entry).
+    """One-token attention updating the STACKED (L, B, Hkv, hd, Smax)
+    caches in place: the decode kernel writes only the token's column of
+    layer ``layer_idx`` and reads the layer through its index map, so a
+    scan carrying the full cache neither slices a layer out nor scatters
+    into it.
 
     ``pos`` may be a scalar or a per-slot ``(B,)`` vector (ragged continuous
     batching: every slot decodes at its own position)."""
@@ -358,14 +399,8 @@ def attention_decode_inplace(cfg: ModelConfig, p: Params, x: jax.Array,
     pos_vec = position_vector(pos, B)
     positions = pos_vec[:, None]
     q, k, v = _qkv(cfg, p, x, x, positions, positions, rope)
-    batch_ix = jnp.arange(B)
-    kfull = kfull.at[layer_idx, batch_ix, :, pos_vec].set(
-        k[:, 0].astype(kfull.dtype))
-    vfull = vfull.at[layer_idx, batch_ix, :, pos_vec].set(
-        v[:, 0].astype(vfull.dtype))
-    kc = jax.lax.dynamic_index_in_dim(kfull, layer_idx, 0, keepdims=False)
-    vc = jax.lax.dynamic_index_in_dim(vfull, layer_idx, 0, keepdims=False)
-    out = decode_attention(cfg, q, kc.astype(cdt), vc.astype(cdt), positions)
+    out, kfull, vfull = decode_attention(cfg, q, kfull, vfull, k, v, pos_vec,
+                                         layer_idx)
     out = out @ p["wo"].astype(cdt)
     return constrain(out, "batch", None, None), kfull, vfull
 
@@ -376,8 +411,8 @@ def attention_prefill_chunk_inplace(cfg: ModelConfig, p: Params,
                                     start: jax.Array, qlen: jax.Array,
                                     positions: jax.Array,
                                     rope: bool = True):
-    """Chunk-of-tokens attention updating the STACKED head-major
-    (L, B, Hkv, Smax, hd) caches in place — the chunked-prefill analogue of
+    """Chunk-of-tokens attention updating the STACKED (L, B, Hkv, hd, Smax)
+    caches in place — the chunked-prefill analogue of
     :func:`attention_decode_inplace`.  ``x``: (B, T, D) chunk activations;
     ``positions``: (B, T) absolute positions (``start[:, None] +
     arange(T)``); padded rows (``i >= qlen[b]``) scatter out of bounds and
@@ -386,13 +421,13 @@ def attention_prefill_chunk_inplace(cfg: ModelConfig, p: Params,
     x = x.astype(cdt)
     B, T, _ = x.shape
     q, k, v = _qkv(cfg, p, x, x, positions, positions, rope)
-    Smax = kfull.shape[3]
+    Smax = kfull.shape[-1]
     batch_ix = jnp.arange(B)[:, None]
     live = jnp.arange(T)[None, :] < qlen[:, None]
     safe_pos = jnp.where(live, positions, Smax)       # OOB rows are dropped
-    kfull = kfull.at[layer_idx, batch_ix, :, safe_pos].set(
+    kfull = kfull.at[layer_idx, batch_ix, :, :, safe_pos].set(
         k.astype(kfull.dtype), mode="drop")
-    vfull = vfull.at[layer_idx, batch_ix, :, safe_pos].set(
+    vfull = vfull.at[layer_idx, batch_ix, :, :, safe_pos].set(
         v.astype(vfull.dtype), mode="drop")
     kc = jax.lax.dynamic_index_in_dim(kfull, layer_idx, 0, keepdims=False)
     vc = jax.lax.dynamic_index_in_dim(vfull, layer_idx, 0, keepdims=False)
@@ -416,24 +451,25 @@ def attention_apply(cfg: ModelConfig, p: Params, x: jax.Array, *,
     cross = kv_src is not None
     causal = cfg.causal if causal is None else causal
     if mode == "decode" and not cross:
-        # project one token; append handled by caller via returned k,v.
+        # project one token and write it into the per-layer cache, handed
+        # to the decode kernel as a one-layer stack and returned as k, v.
         # pos may be scalar or per-slot (B,): each slot writes and masks at
         # its own position (ragged continuous batching)
-        B = x.shape[0]
-        pos_vec = position_vector(pos, B)
         q, k, v = _qkv(cfg, p, x, x, positions, positions, rope)
-        batch_ix = jnp.arange(B)
-        kc = k_cache.astype(cdt).at[batch_ix, :, pos_vec].set(k[:, 0])
-        vc = v_cache.astype(cdt).at[batch_ix, :, pos_vec].set(v[:, 0])
-        out = decode_attention(cfg, q, kc, vc, pos_vec[:, None])
+        out, kc, vc = decode_attention(cfg, q, k_cache[None], v_cache[None],
+                                       k, v, pos, 0)
         out = out @ p["wo"].astype(cdt)
-        return AttnOut(x=constrain(out, "batch", None, None), k=kc, v=vc)
+        return AttnOut(x=constrain(out, "batch", None, None), k=kc[0],
+                       v=vc[0])
     if mode == "decode" and cross:
-        # cross-attn at decode: static KV from the prefill cache
+        # cross-attn at decode: static KV from the prefill cache, every
+        # row live and nothing written
         q, _, _ = _qkv(cfg, p, x, x[:, :1], positions, positions, False)
-        out = decode_attention(cfg, q, k_cache.astype(cdt),
-                               v_cache.astype(cdt),
-                               jnp.asarray(k_cache.shape[2] - 1))
+        B, _, Hq, hd = q.shape
+        last = jnp.full((B,), k_cache.shape[-1] - 1, jnp.int32)
+        out = decode_attend_ref(q.reshape(B, Hq, hd), k_cache.astype(cdt),
+                                v_cache.astype(cdt), last)
+        out = out.reshape(B, 1, Hq * hd).astype(cdt)
         return AttnOut(x=(out @ p["wo"].astype(cdt)))
     src = x if not cross else kv_src.astype(cdt)
     kv_pos = positions if not cross else jnp.arange(src.shape[1])
@@ -444,7 +480,7 @@ def attention_apply(cfg: ModelConfig, p: Params, x: jax.Array, *,
     out = blocked_attention(cfg, q, k, v, causal=causal and not cross)
     out = out @ p["wo"].astype(cdt)
     return AttnOut(x=constrain(out, "batch", None, None),
-                   k=k.transpose(0, 2, 1, 3), v=v.transpose(0, 2, 1, 3))
+                   k=to_kv_layout(k), v=to_kv_layout(v))
 
 
 # ---------------------------------------------------------------------------

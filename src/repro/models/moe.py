@@ -417,29 +417,24 @@ def decode(cfg: ModelConfig, p, token, pos, cache):
 
 
 def cache_spec(cfg: ModelConfig, batch: int, max_seq: int):
-    dt = jnp.dtype(cfg.compute_dtype)
-    kv = (batch, cfg.n_kv_heads, max_seq, cfg.hd)      # head-major
     if cfg.moe_every == 1:
-        shp = (cfg.n_layers, *kv)
-        return {"k": jax.ShapeDtypeStruct(shp, dt),
-                "v": jax.ShapeDtypeStruct(shp, dt)}
+        kv = L.kv_spec(cfg, (cfg.n_layers,), batch, max_seq)
+        return {"k": kv, "v": kv}
     nb = cfg.n_layers // cfg.moe_every
-    per_d = cfg.moe_every - 1
-    return {"k_dense": jax.ShapeDtypeStruct((nb, per_d, *kv), dt),
-            "v_dense": jax.ShapeDtypeStruct((nb, per_d, *kv), dt),
-            "k_moe": jax.ShapeDtypeStruct((nb, *kv), dt),
-            "v_moe": jax.ShapeDtypeStruct((nb, *kv), dt)}
+    dense = L.kv_spec(cfg, (nb, cfg.moe_every - 1), batch, max_seq)
+    moe = L.kv_spec(cfg, (nb,), batch, max_seq)
+    return {"k_dense": dense, "v_dense": dense, "k_moe": moe, "v_moe": moe}
 
 
 def cache_logical_axes(cfg: ModelConfig):
-    ax = ("batch", None, "seq_mp", None)
     if cfg.moe_every == 1:
-        return {"k": (None, *ax), "v": (None, *ax)}
-    return {"k_dense": (None, None, *ax), "v_dense": (None, None, *ax),
-            "k_moe": (None, *ax), "v_moe": (None, *ax)}
+        return {"k": L.kv_logical_axes(1), "v": L.kv_logical_axes(1)}
+    return {"k_dense": L.kv_logical_axes(2), "v_dense": L.kv_logical_axes(2),
+            "k_moe": L.kv_logical_axes(1), "v_moe": L.kv_logical_axes(1)}
 
 
 def cache_seq_axes(cfg: ModelConfig):
     if cfg.moe_every == 1:
-        return {"k": 3, "v": 3}
-    return {"k_dense": 4, "v_dense": 4, "k_moe": 3, "v_moe": 3}
+        return {"k": L.kv_seq_axis(1), "v": L.kv_seq_axis(1)}
+    return {"k_dense": L.kv_seq_axis(2), "v_dense": L.kv_seq_axis(2),
+            "k_moe": L.kv_seq_axis(1), "v_moe": L.kv_seq_axis(1)}

@@ -133,12 +133,12 @@ def prefill(cfg: ModelConfig, p, batch):
     x, (ks, vs) = jax.lax.scan(body, x, p["layers"])
     x = L.apply_norm(p["ln_f"], x, cfg.norm)
     logits = L.lm_head(cfg, p["tok"], x[:, -1:])
-    return logits, {"k": ks, "v": vs}        # (L, B, Hkv, S, hd)
+    return logits, {"k": ks, "v": vs}        # (L, B, Hkv, hd, S)
 
 
 def prefill_chunk(cfg: ModelConfig, p, tokens, cache, start, qlen):
-    """Consume one fixed-size prompt chunk against growing head-major
-    (L, B, Hkv, Smax, hd) caches — the chunked-prefill admission path.  ``tokens``:
+    """Consume one fixed-size prompt chunk against growing
+    (L, B, Hkv, hd, Smax) caches — the chunked-prefill admission path.  ``tokens``:
     (B, T) chunk ids (rows past ``qlen[b]`` are padding); ``start``: (B,)
     absolute position of each slot's first chunk token; ``qlen``: (B,) live
     tokens.  The stacked caches ride the scan carry and take a T-row
@@ -169,9 +169,10 @@ def prefill_chunk(cfg: ModelConfig, p, tokens, cache, start, qlen):
 
 
 def decode(cfg: ModelConfig, p, token, pos, cache):
-    """One decode step against head-major (L, B, Hkv, Smax, hd) caches.  The stacked
-    caches ride the scan carry and are updated in place (token-slice DUS),
-    so per-layer traffic is the attention read + a 1-token write.  ``pos``
+    """One decode step against (L, B, Hkv, hd, Smax) caches.  The stacked
+    caches ride the scan carry and the decode kernel updates them in place
+    (one block per slot and head), so per-layer traffic is the attention
+    read + a 1-token write.  ``pos``
     is a scalar or a per-slot (B,) vector — ragged batches decode each slot
     at its own position.  This is also the single-step body
     ``Model.decode_fused`` scans k times with the cache donated: all
@@ -194,18 +195,15 @@ def decode(cfg: ModelConfig, p, token, pos, cache):
 
 
 def cache_spec(cfg: ModelConfig, batch: int, max_seq: int):
-    shp = (cfg.n_layers, batch, cfg.n_kv_heads, max_seq, cfg.hd)
-    dt = jnp.dtype(cfg.compute_dtype)
-    return {"k": jax.ShapeDtypeStruct(shp, dt),
-            "v": jax.ShapeDtypeStruct(shp, dt)}
+    kv = L.kv_spec(cfg, (cfg.n_layers,), batch, max_seq)
+    return {"k": kv, "v": kv}
 
 
 def cache_logical_axes(cfg: ModelConfig):
-    return {"k": (None, "batch", None, "seq_mp", None),
-            "v": (None, "batch", None, "seq_mp", None)}
+    return {"k": L.kv_logical_axes(1), "v": L.kv_logical_axes(1)}
 
 
 def cache_seq_axes(cfg: ModelConfig):
     """Axis index (in the full cache leaf) that grows with decode position;
     None = fixed-size state.  Used by session extract/insert."""
-    return {"k": 3, "v": 3}
+    return {"k": L.kv_seq_axis(1), "v": L.kv_seq_axis(1)}

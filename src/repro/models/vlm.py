@@ -181,26 +181,19 @@ def decode(cfg: ModelConfig, p, token, pos, cache):
 def cache_spec(cfg: ModelConfig, batch: int, max_seq: int):
     nb = cfg.n_layers // cfg.cross_attn_every
     per_self = cfg.cross_attn_every - 1
-    cdt = jnp.dtype(cfg.compute_dtype)
-    H, hd = cfg.n_kv_heads, cfg.hd          # head-major KV
-    return {
-        "k_self": jax.ShapeDtypeStruct((nb, per_self, batch, H, max_seq, hd), cdt),
-        "v_self": jax.ShapeDtypeStruct((nb, per_self, batch, H, max_seq, hd), cdt),
-        "k_cross": jax.ShapeDtypeStruct((nb, batch, H, cfg.n_image_tokens, hd), cdt),
-        "v_cross": jax.ShapeDtypeStruct((nb, batch, H, cfg.n_image_tokens, hd), cdt),
-    }
+    self_kv = L.kv_spec(cfg, (nb, per_self), batch, max_seq)
+    cross_kv = L.kv_spec(cfg, (nb,), batch, cfg.n_image_tokens)
+    return {"k_self": self_kv, "v_self": self_kv,
+            "k_cross": cross_kv, "v_cross": cross_kv}
 
 
 def cache_logical_axes(cfg: ModelConfig):
-    return {
-        "k_self": (None, None, "batch", None, "seq_mp", None),
-        "v_self": (None, None, "batch", None, "seq_mp", None),
-        "k_cross": (None, "batch", None, "seq_mp", None),
-        "v_cross": (None, "batch", None, "seq_mp", None),
-    }
+    return {"k_self": L.kv_logical_axes(2), "v_self": L.kv_logical_axes(2),
+            "k_cross": L.kv_logical_axes(1), "v_cross": L.kv_logical_axes(1)}
 
 
 def cache_seq_axes(cfg: ModelConfig):
     # cross-KV spans the (fixed) image tokens, not the decode position —
     # carried whole in sessions, never trimmed
-    return {"k_self": 4, "v_self": 4, "k_cross": None, "v_cross": None}
+    return {"k_self": L.kv_seq_axis(2), "v_self": L.kv_seq_axis(2),
+            "k_cross": None, "v_cross": None}

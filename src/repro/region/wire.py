@@ -43,15 +43,16 @@ WIRE_MAGIC = b"RSES"
 # "prefilled" (the session left its source mid-prefill with that many
 # prompt tokens consumed — see Session.prefilled) and "delivery" (the
 # monotonic ``(origin, rid, epoch)`` id adoption dedups on, so a retried
-# ship never double-adopts — see Session.delivery).  v5 changed the
-# layout: attention KV leaves went from sequence-major ``(..., B, S, Hkv,
-# hd)`` to head-major ``(..., B, Hkv, S, hd)``, the TPU kernels' block
-# layout.  A v1-v4 payload would be inserted as transposed KV (silently,
-# when its length is at most Hkv), so this build reads v5 only and
-# refuses older payloads loudly.  Writers always emit WIRE_VERSION;
-# readers accept exactly WIRE_COMPAT.
-WIRE_VERSION = 5
-WIRE_COMPAT = frozenset({5})
+# ship never double-adopts — see Session.delivery).  v5 and v6 changed
+# the layout of the attention KV leaves: v1-v4 carried ``(..., B, S, Hkv,
+# hd)``, v5 ``(..., B, Hkv, S, hd)`` and v6 the sequence-minor cache
+# layout ``(..., B, Hkv, hd, S)`` (``models.layers.kv_spec``).  An older
+# payload would be inserted as transposed KV (silently, when its length
+# fits the axis it lands on), so this build reads v6 only and refuses
+# older payloads loudly.  Writers always emit WIRE_VERSION; readers accept
+# exactly WIRE_COMPAT.
+WIRE_VERSION = 6
+WIRE_COMPAT = frozenset({6})
 _CODEC_IDS = {"zlib": 0, "zstd": 1}
 _CODEC_NAMES = {v: k for k, v in _CODEC_IDS.items()}
 # magic(4) + version(1) + codec(1) + crc32(4)

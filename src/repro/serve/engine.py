@@ -99,7 +99,7 @@ class Session:
 @dataclasses.dataclass
 class _Prefill:
     """An in-progress chunked prefill: the request plus its own growing
-    (L, 1, max_seq, ...) device cache, donated back into the jit every
+    (L, 1, ..., max_seq) device cache, donated back into the jit every
     chunk.  Lives outside the batch slots — a 32k prompt prefilling in
     chunks never blocks a decode slot."""
     req: Request

@@ -117,24 +117,35 @@ def test_ragged_pallas_kernel_token_identity(arch):
 
 
 def test_ragged_kernel_matches_reference_numerically():
-    """Direct op-level check: GQA, ragged per-slot positions, and a cache
-    length that does not divide the k-block."""
+    """Direct op-level check on a stacked cache: GQA, ragged per-slot
+    positions at layer 1 of 2; a cache length that does not divide the
+    k-block is refused (an aliased cache cannot be padded)."""
+    import jax.numpy as jnp
     rng = np.random.default_rng(3)
     for (B, Smax, Hq, Hkv, hd, bk) in ((4, 32, 8, 2, 16, 8),
                                        (3, 19, 6, 6, 8, 8)):
-        import jax.numpy as jnp
         q = jnp.asarray(rng.normal(size=(B, Hq, hd)), jnp.float32)
-        k = jnp.asarray(rng.normal(size=(B, Hkv, Smax, hd)), jnp.float32)
-        v = jnp.asarray(rng.normal(size=(B, Hkv, Smax, hd)), jnp.float32)
+        k = jnp.asarray(rng.normal(size=(2, B, Hkv, hd, Smax)), jnp.float32)
+        v = jnp.asarray(rng.normal(size=(2, B, Hkv, hd, Smax)), jnp.float32)
+        kn = jnp.asarray(rng.normal(size=(B, Hkv, hd)), jnp.float32)
+        vn = jnp.asarray(rng.normal(size=(B, Hkv, hd)), jnp.float32)
         pos = jnp.asarray(rng.integers(0, Smax, B), jnp.int32)
-        ref = ragged_decode_ref(q, k, v, pos)
+        args = (q, k, v, kn, vn, pos, 1)
+        if Smax % bk:
+            with force_pallas(), pytest.raises(ValueError, match="multiple"):
+                ragged_decode_attention(*args, block_k=bk)
+            continue
+        ref = ragged_decode_ref(*args)
         with force_pallas():
-            out = ragged_decode_attention(q, k, v, pos, block_k=bk)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+            out = ragged_decode_attention(*args, block_k=bk)
+        np.testing.assert_allclose(np.asarray(out[0]), np.asarray(ref[0]),
                                    rtol=1e-5, atol=1e-5)
-    # and the default (CPU) route IS the reference
-    got = ragged_decode_attention(q, k, v, pos)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+        for got, want in zip(out[1:], ref[1:]):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        # and the default (CPU) route IS the reference
+        got = ragged_decode_attention(*args)
+        for g, r in zip(got, ref):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
 
 
 def test_donated_cache_is_consumed():

@@ -389,8 +389,8 @@ def test_wire_v3_prefilled_roundtrip_and_compat():
     full = Session(req=req, pos=3, cur_token=9,
                    cache={"k": np.ones((2, 3, 4), np.float32)})
     assert decode_session(encode_session(full)).prefilled is None
-    # a v2 header over the same body is refused: pre-v5 payloads carry
-    # sequence-major KV, and a v5 reader must not guess at their layout
+    # a v2 header over the same body is refused: pre-v6 payloads carry KV
+    # in older layouts, and a v6 reader must not guess at their layout
     import struct
     hdr = struct.Struct(">4sBBI")
     magic, ver, codec, crc = hdr.unpack_from(data)

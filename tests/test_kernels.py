@@ -90,23 +90,56 @@ def test_stream_sweep(n, block, dtype):
                                rtol=2e-2, atol=2e-2)
 
 
-@pytest.mark.parametrize("B,Hq,Hkv,Smax,hd,bk", [
-    (2, 4, 2, 64, 32, 32),       # GQA rep 2
-    (3, 4, 4, 96, 16, 48),       # MHA, uneven block
+def _decode_case(L, layer, Hq, Hkv, Smax, hd, bk, pos, tag=""):
+    # ids read B-Hq-Hkv-Smax-hd-bk, as the cases were named before the
+    # cache was stacked
+    return pytest.param(L, layer, Hq, Hkv, Smax, hd, bk, pos,
+                        id=f"{len(pos)}-{Hq}-{Hkv}-{Smax}-{hd}-{bk}{tag}")
+
+
+@pytest.mark.parametrize("L,layer,Hq,Hkv,Smax,hd,bk,pos", [
+    # GQA rep 2: a block start and a block end
+    _decode_case(2, 1, 4, 2, 64, 32, 32, (32, 31)),
+    # MHA, 2 blocks: a block start, a block end, the last row
+    _decode_case(2, 1, 4, 4, 96, 16, 48, (48, 47, 95)),
+    # layer 0 and a layer above 0: an idle slot (pinned at 0) and a
+    # position past the cache (dropped) besides
+    _decode_case(2, 0, 4, 2, 64, 32, 16, (16, 15, 63, 0, 70), "-layer0"),
+    _decode_case(3, 2, 4, 2, 64, 32, 16, (32, 47, 63, 0, 64), "-layer2"),
+    _decode_case(1, 0, 6, 2, 32, 16, 128, (0, 31, 17), "-one-block"),
+    _decode_case(1, 0, 4, 2, 96, 16, 64, (5,), "-not-dividing"),
 ])
-def test_ragged_decode_parity(B, Hq, Hkv, Smax, hd, bk):
+def test_ragged_decode_parity(L, layer, Hq, Hkv, Smax, hd, bk, pos):
     """Pallas ragged decode attention (interpret mode, via force_pallas)
-    matches the jnp oracle at mixed per-slot positions."""
+    matches the jnp oracle at mixed per-slot positions, and writes each
+    slot's new K/V column at its position of ``layer`` and nothing else:
+    every other layer, slot and row comes back byte-identical."""
+    B = len(pos)
     q = jnp.asarray(RNG.standard_normal((B, Hq, hd)), jnp.float32)
-    k = jnp.asarray(RNG.standard_normal((B, Hkv, Smax, hd)), jnp.float32)
-    v = jnp.asarray(RNG.standard_normal((B, Hkv, Smax, hd)), jnp.float32)
-    pos = jnp.asarray(RNG.integers(0, Smax, (B,)), jnp.int32)
+    k = jnp.asarray(RNG.standard_normal((L, B, Hkv, hd, Smax)), jnp.float32)
+    v = jnp.asarray(RNG.standard_normal((L, B, Hkv, hd, Smax)), jnp.float32)
+    kn = jnp.asarray(RNG.standard_normal((B, Hkv, hd)), jnp.float32)
+    vn = jnp.asarray(RNG.standard_normal((B, Hkv, hd)), jnp.float32)
+    pos = jnp.asarray(pos, jnp.int32)
+    args = (q, k, v, kn, vn, pos, layer)
+    if Smax % min(bk, Smax):
+        # an aliased cache cannot be padded: the block must divide Smax
+        with ragged_decode_ops.force_pallas(), pytest.raises(ValueError):
+            ragged_decode_ops.ragged_decode_attention(*args, block_k=bk)
+        return
     with ragged_decode_ops.force_pallas():
-        got = ragged_decode_ops.ragged_decode_attention(q, k, v, pos,
-                                                        block_k=bk)
-    ref = ragged_decode_ref(q, k, v, pos)
+        got, gk, gv = ragged_decode_ops.ragged_decode_attention(
+            *args, block_k=bk)
+    ref, rk, rv = ragged_decode_ref(*args)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-5, atol=2e-4)
+    for cache, new, outs in ((k, kn, (gk, rk)), (v, vn, (gv, rv))):
+        want = np.array(cache)
+        for b, p in enumerate(np.asarray(pos)):
+            if p < Smax:
+                want[layer, b, :, :, p] = np.asarray(new[b])
+        for out in outs:
+            np.testing.assert_array_equal(np.asarray(out), want)
 
 
 @pytest.mark.parametrize("B,T,Hq,Hkv,Smax,hd,bk", [

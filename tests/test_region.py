@@ -110,28 +110,30 @@ def test_wire_rejects_corrupt_and_foreign_payloads():
 
 
 def test_wire_v1_payload_still_decodes():
-    """v1-v4 payloads carry sequence-major KV; v5 moved it to head-major,
-    so an older payload no longer decodes: it is refused at the header,
-    never inserted as transposed KV.  Optional keys stay optional: a v5
-    payload without "trace"/"prefilled"/"delivery" decodes with None."""
-    assert WIRE_VERSION == 5 and WIRE_COMPAT == frozenset({5})
+    """v1-v5 payloads carry KV in older layouts; v6 carries the
+    sequence-minor cache layout, so an older payload no longer decodes: it
+    is refused at the header, never inserted as transposed KV.  Optional
+    keys stay optional: a v6 payload without "trace"/"prefilled"/"delivery"
+    decodes with None."""
+    assert WIRE_VERSION == 6 and WIRE_COMPAT == frozenset({6})
     sess = _synthetic_session()
     assert sess.trace is None
     data = encode_session(sess)
     out = decode_session(data)
     assert out.trace is None and out.prefilled is None
     assert out.delivery is None
-    for old in (1, 2, 3, 4):
+    for old in (1, 2, 3, 4, 5):
         buf = bytearray(data)
         buf[4] = old
         with pytest.raises(WireFormatError, match="version"):
             decode_session(bytes(buf))
 
 
-@pytest.mark.parametrize("version", [1, 4])
+@pytest.mark.parametrize("version", [1, 4, 5])
 def test_wire_pre_v5_session_refused_by_engine(version):
-    """A live session shipped by a pre-v5 (sequence-major KV) writer is
-    refused by the importing engine, which keeps nothing of it."""
+    """A live session shipped by a writer older than v6 (KV in an older
+    layout) is refused by the importing engine, which keeps nothing of
+    it."""
     cfg = get_config("smollm-135m", reduced=True)
     m = get_model(cfg)
     params, _ = m.init(jax.random.PRNGKey(0))
