@@ -2,8 +2,9 @@
 
 Once the window has closed and the program's state is freed, a sample of
 the requests it finished, drawn from the seed and always holding the
-longest, is run through the float32 reference (``bench/reference.py``)
-over each prompt followed by its served tokens.  For every served token
+longest, is run through the float32 reference of the configuration's
+architecture file (``bench/archs/<arch>.py``, ``logits_at``) over each
+prompt followed by its served tokens.  For every served token
 the number compared is how far its reference logit lies below the
 reference's best at that position; the run is correct while the widest of
 those gaps stays under the configuration's limit.  Served tokens are
@@ -24,16 +25,19 @@ import numpy as np
 from bench import reference
 
 
-def gap_fn(published: dict, arch: dict, control: bool = False):
+def gap_fn(arch, config: dict, control: bool = False):
     """Jitted ``(w, tokens (S,), rows (R,), served (R,), live (R,)) ->
-    widest gap``, at ``highest`` precision.  With ``control`` the served
-    tokens are ignored and the float8 reference's own choices are judged
-    instead."""
+    widest gap``, at ``highest`` precision, on the reference of the
+    architecture file ``arch`` for ``config``.  With ``control`` the
+    served tokens are ignored and the float8 reference's own choices are
+    judged instead."""
+    hf, settings = config["published"], config["architecture"]
+
     def gaps(w, tokens, rows, served, live):
-        ref = reference.logits_at(published, arch, w, tokens, rows)
+        ref = arch.logits_at(hf, settings, w, tokens, rows)
         if control:
-            low = reference.logits_at(published, arch, w, tokens, rows,
-                                      reference.fp8)
+            low = arch.logits_at(hf, settings, w, tokens, rows,
+                                 reference.fp8)
             served = jnp.argmax(low, axis=-1)
         chosen = jnp.take_along_axis(ref, served[:, None], axis=-1)[:, 0]
         g = jnp.max(ref, axis=-1) - chosen

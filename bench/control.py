@@ -37,8 +37,7 @@ def read(cell, seed: int, seconds: float, require_chip: bool = True) -> dict:
     both judged by the run's own checks and limits."""
     from bench import check
     from bench.run import judge, run_cell
-    hf, arch = cell.config["published"], cell.config["architecture"]
-    control_fn = check.gap_fn(hf, arch, control=True)
+    control_fn = check.gap_fn(cell.arch, cell.config, control=True)
     got = {}
 
     def on_check(w, pairs):

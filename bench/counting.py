@@ -7,8 +7,9 @@ tokens a decode chunk computes past a request's end do not count.  So the
 counted work never exceeds what the chip really did, and a roofline share
 built on it can pass 100% only if the device time is read too short.
 
-Sizes come from the configuration file's published keys
-(``hidden_size``, ``num_attention_heads`` ...), never from the program.
+Sizes come from the configuration file's published keys, read by its
+architecture file (``bench/archs/<arch>.py``, ``dims``), never from the
+program.
 """
 
 from __future__ import annotations
@@ -42,24 +43,20 @@ def peaks_for(device_kind: str, path: pathlib.Path = PEAKS) -> Peaks:
 
 @dataclasses.dataclass(frozen=True)
 class Dims:
+    """What the counts read of a configuration, from its architecture
+    file's ``dims``: the attention sizes the kernels see, the number of
+    attention layers, the LM head's sizes, and ``matmul_flops_per_token``,
+    the weight-matmul FLOPs of one token through the layers this chip
+    holds (attention and the LM head excluded)."""
     layers: int
     d_model: int
     heads: int
     kv_heads: int
     head_dim: int
-    d_ff: int
     vocab: int
+    matmul_flops_per_token: float
     kv_bytes: int = 2          # bf16 cache and kernel inputs
     out_bytes: int = 4         # the kernels return float32
-
-    @classmethod
-    def from_published(cls, hf: dict) -> "Dims":
-        heads = hf["num_attention_heads"]
-        return cls(layers=hf["num_hidden_layers"], d_model=hf["hidden_size"],
-                   heads=heads, kv_heads=hf["num_key_value_heads"],
-                   head_dim=hf.get("head_dim",
-                                   hf["hidden_size"] // heads),
-                   d_ff=hf["intermediate_size"], vocab=hf["vocab_size"])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,15 +97,6 @@ def ragged_prefill_call(d: Dims, start: int, qlen: int) -> Cost:
     return Cost(flops, byt)
 
 
-def matmul_flops_per_token(d: Dims) -> float:
-    """Weight-matmul FLOPs of one token through every layer (QKV, output
-    projection, gated MLP), without the LM head and without attention."""
-    qkv = d.d_model * (d.heads + 2 * d.kv_heads) * d.head_dim
-    out = d.heads * d.head_dim * d.d_model
-    mlp = 3 * d.d_model * d.d_ff
-    return 2.0 * d.layers * (qkv + out + mlp)
-
-
 def lm_head_flops(d: Dims) -> float:
     return 2.0 * d.d_model * d.vocab
 
@@ -118,7 +106,7 @@ def decode_model_flops(d: Dims, rows: list[int]) -> float:
     ``i`` attended (its position plus one).  Each token pays every layer's
     matmuls, its attention, and the LM head."""
     attn = 4.0 * d.heads * d.head_dim * sum(rows) * d.layers
-    return len(rows) * (matmul_flops_per_token(d) + lm_head_flops(d)) + attn
+    return len(rows) * (d.matmul_flops_per_token + lm_head_flops(d)) + attn
 
 
 def prefill_model_flops(d: Dims, start: int, qlen: int,
@@ -128,4 +116,4 @@ def prefill_model_flops(d: Dims, start: int, qlen: int,
     whose logits are used."""
     attn = ragged_prefill_call(d, start, qlen).flops * d.layers
     head = lm_head_flops(d) if last_chunk else 0.0
-    return qlen * matmul_flops_per_token(d) + attn + head
+    return qlen * d.matmul_flops_per_token + attn + head

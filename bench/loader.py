@@ -3,8 +3,13 @@ name.
 
 ``BENCHMARK.json`` names them; each lives in a file of its own:
 
-* ``bench/configs/<config>.json``: the model, its published sizes, the
-  engine settings, replicas, router and admission, weights and the check;
+* ``bench/configs/<config>.json``: the model, the architecture file it is
+  measured with (``arch``), its published sizes, the engine settings,
+  replicas, router and admission, weights and the check;
+* ``bench/archs/<arch>.py``: an architecture, with
+  ``program_want(published, arch)``, ``make(published, arch, key)``,
+  ``logits_at(published, arch, w, tokens, rows, rnd)`` and
+  ``dims(published)`` (see ``bench/archs/dense.py``);
 * ``bench/traffic/<mix>.json``: the arrival process, lengths, limits;
 * ``bench/metrics/<metric>.py``: a reader with ``read(run) -> float | None``.
 
@@ -18,6 +23,7 @@ import importlib.util
 import json
 import pathlib
 import re
+import types
 
 BENCH = pathlib.Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -58,23 +64,39 @@ class Cell:
     end_to_end: list[dict]
     per_layer: list[dict]
     readers: dict                  # metric name -> module with read(run)
+    arch: types.ModuleType         # bench/archs/<config["arch"]>.py
 
 
 def load_benchmark(root: pathlib.Path = ROOT) -> dict:
     return _json(root / "BENCHMARK.json")
 
 
-def load_reader(name: str, bench: pathlib.Path = BENCH):
-    path = bench / "metrics" / f"{check_name(name, 'metric')}.py"
+def _load_module(kind: str, name: str, path: pathlib.Path,
+                 api: tuple[str, ...]) -> types.ModuleType:
+    """The module at ``path``, which must define the callables ``api``."""
     if not path.is_file():
-        raise BenchError(f"no reader {path} for metric {name!r}")
+        raise BenchError(f"no {kind} file {path} for {name!r}")
     spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_').replace('-', '_')}", path)
+        f"bench_{kind}_{name.replace('.', '_').replace('-', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    if not callable(getattr(mod, "read", None)):
-        raise BenchError(f"{path} has no read(run)")
+    missing = [f for f in api if not callable(getattr(mod, f, None))]
+    if missing:
+        raise BenchError(f"{path} defines no {', '.join(missing)}")
     return mod
+
+
+def load_reader(name: str, bench: pathlib.Path = BENCH):
+    path = bench / "metrics" / f"{check_name(name, 'metric')}.py"
+    return _load_module("metric", name, path, ("read",))
+
+
+ARCH_API = ("program_want", "make", "logits_at", "dims")
+
+
+def load_arch(name: str, bench: pathlib.Path = BENCH):
+    path = bench / "archs" / f"{check_name(name, 'architecture')}.py"
+    return _load_module("arch", name, path, ARCH_API)
 
 
 def _applies(metric: dict, cell: str) -> bool:
@@ -93,6 +115,10 @@ def load_cell(name: str, root: pathlib.Path = ROOT,
     for key in ("name", "config", "traffic"):
         check_name(w[key], key)
     config = _json(bench / "configs" / f"{w['config']}.json")
+    if "arch" not in config:
+        raise BenchError(f"configuration {w['config']!r} names no "
+                         f"architecture file (key 'arch')")
+    arch = load_arch(config["arch"], bench)
     traffic = _json(bench / "traffic" / f"{w['traffic']}.json")
     e2e = [m for m in spec["end_to_end"] if _applies(m, name)]
     layer = [m for m in spec["per_layer"] if _applies(m, name)]
@@ -102,4 +128,4 @@ def load_cell(name: str, root: pathlib.Path = ROOT,
     readers = {m["name"]: load_reader(m["name"], bench) for m in layer}
     return Cell(name=name, chips=int(w["chips"]), config=config,
                 traffic=traffic, end_to_end=e2e, per_layer=layer,
-                readers=readers)
+                readers=readers, arch=arch)

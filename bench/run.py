@@ -9,7 +9,8 @@ In order: load the cell's configuration and traffic mix (by the names in
 the engines (and the gateway) as the configuration states, warm up every
 program the traffic drives, play ``steady_s`` seconds of the open-loop
 traffic, measure for ``--seconds``, stop arrivals and drain, then check
-what was served against the float32 reference.
+what was served against the float32 reference of the configuration's
+architecture file.
 
 ``--trace 0`` reports the cell's end-to-end metrics; ``--trace 1`` records
 a profiler trace of a few seconds of the window and reports the per-layer
@@ -84,26 +85,21 @@ def program_norm_eps(cfg) -> float:
     return (a / float(out[0, 0])) ** 2 - a * a
 
 
-def program_config(config: dict):
-    """The program's model configuration, held to the published sizes and
-    to the published RMSNorm epsilon."""
+def program_config(config: dict, arch):
+    """The program's model configuration, held to what the architecture
+    file ``arch`` reads off the configuration (``program_want``), to its
+    weight type and to the published RMSNorm epsilon."""
     import dataclasses
     import math
     from repro.configs import get_config
     from bench.loader import BenchError
+    from bench.weights import param_dtype
     cfg = get_config(config["model"])
     if config.get("model_overrides"):
         cfg = dataclasses.replace(cfg, **config["model_overrides"])
-    hf, arch = config["published"], config["architecture"]
-    want = {"d_model": hf["hidden_size"], "n_layers": hf["num_hidden_layers"],
-            "n_heads": hf["num_attention_heads"],
-            "n_kv_heads": hf["num_key_value_heads"],
-            "d_ff": hf["intermediate_size"], "vocab": hf["vocab_size"],
-            "rope_theta": hf["rope_theta"],
-            "tie_embeddings": hf["tie_word_embeddings"],
-            "qkv_bias": arch["qkv_bias"], "hd": hf.get(
-                "head_dim", hf["hidden_size"] // hf["num_attention_heads"]),
-            "compute_dtype": arch["compute_dtype"]}
+    hf = config["published"]
+    want = dict(arch.program_want(hf, config["architecture"]),
+                param_dtype=param_dtype(config))
     got = {k: getattr(cfg, k) for k in want}
     bad = {k: (got[k], want[k]) for k in want if got[k] != want[k]}
     eps = program_norm_eps(cfg)
@@ -149,14 +145,13 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
         log(f"compile cache: {enable_cache()}")
     meter = CompileMeter()
     config, mix = cell.config, cell.traffic
-    hf, arch = config["published"], config["architecture"]
-    cfg = program_config(config)
-    dims = counting.Dims.from_published(hf)
+    cfg = program_config(config, cell.arch)
+    dims = cell.arch.dims(config["published"])
     replicas = int(config["replicas"])
     if replicas > len(devs):
         raise NoChip(f"{replicas} replicas need {replicas} devices")
 
-    w = weights.make_on_device(hf, arch, seed31(seed, 3), devs[0])
+    w = weights.make_on_device(cell.arch, config, seed31(seed, 3), devs[0])
     params = [w] + [jax.device_put(w, d) for d in devs[1:replicas]]
     system = driver.build_system(config, cfg, params)
     driver.warm_up(system, config, cfg.vocab)
@@ -293,7 +288,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     gc.collect()
     eng = config["engine"]
     t_check = time.perf_counter()
-    fn = check.gap_fn(hf, arch)
+    fn = check.gap_fn(cell.arch, config)
     gap = check.widest_gap(fn, w, pairs, eng["max_seq"],
                            mix["output"]["max"])
     served = sum(len(q.out_tokens) for _, q in pairs)

@@ -53,9 +53,9 @@ def main(argv=None) -> int:
         print(f"sweep: needs {config['replicas']} TPU chips", file=sys.stderr)
         return 2
     enable_cache()
-    cfg = program_config(config)
-    hf, arch = config["published"], config["architecture"]
-    w = weights.make_on_device(hf, arch, seed31(args.seed, 3), devs[0])
+    cfg = program_config(config, cell.arch)
+    w = weights.make_on_device(cell.arch, config, seed31(args.seed, 3),
+                               devs[0])
     params = [w] + [jax.device_put(w, d)
                     for d in devs[1:config["replicas"]]]
     system = driver.build_system(config, cfg, params)

@@ -1,14 +1,17 @@
 """Operations and bytes of the kernels and model steps, against values
-worked out by hand, and the peaks table."""
+worked out by hand, and the peaks table.  The sizes come through the dense
+architecture file, as a cell's do."""
 
 import json
 
 import pytest
 
-from bench import counting
+from bench import counting, loader
 
-D = counting.Dims(layers=2, d_model=8, heads=4, kv_heads=2, head_dim=2,
-                  d_ff=16, vocab=10)
+DENSE = loader.load_arch("dense")
+D = DENSE.dims({"hidden_size": 8, "intermediate_size": 16,
+                "num_attention_heads": 4, "num_key_value_heads": 2,
+                "head_dim": 2, "num_hidden_layers": 2, "vocab_size": 10})
 PEAKS = counting.Peaks(flops_per_s=1e12, bytes_per_s=1e9, hbm_bytes=1e9,
                        source="test")
 
@@ -34,7 +37,7 @@ def test_prefill_call_by_hand():
 def test_model_flops_by_hand():
     # per token per layer: qkv 8 * (4 + 4) * 2, out 8 * 8, mlp 3 * 8 * 16
     per_layer = 8 * 8 * 2 + 8 * 8 + 3 * 8 * 16
-    assert counting.matmul_flops_per_token(D) == 2 * 2 * per_layer
+    assert D.matmul_flops_per_token == 2 * 2 * per_layer
     assert counting.lm_head_flops(D) == 2 * 8 * 10
     dec = counting.decode_model_flops(D, [10, 20])
     assert dec == 2 * (2 * 2 * per_layer + 160) + 32 * 30 * 2
@@ -68,7 +71,7 @@ def test_useful_work_never_exceeds_what_the_kernel_does(rows):
     """The roofline share cannot pass 100% by construction: the counted
     bytes and FLOPs are at most what the kernel really moves and computes,
     which reads whole 128-row blocks for every slot, idle ones included."""
-    d = counting.Dims.from_published({
+    d = DENSE.dims({
         "hidden_size": 896, "intermediate_size": 4864,
         "num_attention_heads": 14, "num_key_value_heads": 2,
         "num_hidden_layers": 24, "vocab_size": 151936})

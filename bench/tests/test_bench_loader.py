@@ -1,6 +1,8 @@
 """Every cell resolves to its files by name, names and units keep to their
-alphabet, and a new configuration, mix and metric dropped into a fresh
-root are found with no edit to any file that is there."""
+alphabet, a new configuration, architecture, mix and metric dropped into a
+fresh root are found with no edit to any file that is there, and a
+configuration that names no architecture file, or one that is not there,
+is refused."""
 
 import json
 import re
@@ -23,6 +25,8 @@ def test_every_workload_resolves(cell):
     assert len(c.end_to_end) >= 2 and c.per_layer
     for m in c.per_layer:
         assert callable(c.readers[m["name"]].read)
+    assert c.arch.__file__.endswith(f"archs/{c.config['arch']}.py")
+    assert all(callable(getattr(c.arch, f)) for f in loader.ARCH_API)
 
 
 def test_names_and_units_keep_to_their_alphabet():
@@ -52,11 +56,25 @@ def test_files_match_the_benchmark():
         assert m["moves"] in moves
 
 
-def test_new_config_mix_and_metric_are_found_by_name(tmp_path):
+TOY_ARCH = """
+def program_want(published, arch):
+    return {}
+def make(published, arch, key):
+    return {}
+def logits_at(published, arch, w, tokens, rows, rnd=None):
+    return None
+def dims(published):
+    return published["layers"]
+"""
+
+
+def _toy_root(tmp_path, config: dict):
     bench = tmp_path / "bench"
-    for d in ("configs", "traffic", "metrics"):
+    for d in ("configs", "traffic", "metrics", "archs"):
         (bench / d).mkdir(parents=True)
-    (bench / "configs" / "toy.json").write_text(json.dumps({"name": "toy"}))
+    (bench / "configs" / "toy.json").write_text(json.dumps(config))
+    (bench / "archs" / "toy-arch.py").write_text(TOY_ARCH)
+    (bench / "archs" / "broken.py").write_text("def make(): pass\n")
     (bench / "traffic" / "burst.json").write_text(
         json.dumps({"name": "burst", "arrivals": {"rate_per_s": 1.0}}))
     (bench / "metrics" / "toy.widget_ms.py").write_text(
@@ -69,8 +87,38 @@ def test_new_config_mix_and_metric_are_found_by_name(tmp_path):
                           "layer": "toy", "moves": "setup_s",
                           "workloads": ["toy.burst"]}]
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
-    cell = loader.load_cell("toy.burst", root=tmp_path)
-    assert cell.config == {"name": "toy"}
+    return tmp_path
+
+
+def test_new_config_mix_and_metric_are_found_by_name(tmp_path):
+    root = _toy_root(tmp_path, {"name": "toy", "arch": "toy-arch"})
+    cell = loader.load_cell("toy.burst", root=root)
+    assert cell.config == {"name": "toy", "arch": "toy-arch"}
     assert cell.readers["toy.widget_ms"].read(None) == 1.5
+    assert cell.arch.dims({"layers": 3}) == 3
     with pytest.raises(loader.BenchError):
-        loader.load_cell("toy.missing", root=tmp_path)
+        loader.load_cell("toy.missing", root=root)
+
+
+@pytest.mark.parametrize("arch,match", [(None, "names no architecture"),
+                                        ("absent", "no arch file"),
+                                        ("broken", "defines no program_want"),
+                                        ("../toy-arch", "not a valid name")])
+def test_config_without_a_known_architecture_is_refused(tmp_path, arch,
+                                                       match):
+    config = {"name": "toy"} if arch is None else {"name": "toy",
+                                                   "arch": arch}
+    root = _toy_root(tmp_path, config)
+    with pytest.raises(loader.BenchError, match=match):
+        loader.load_cell("toy.burst", root=root)
+
+
+def test_run_exits_2_on_a_config_without_an_architecture(tmp_path,
+                                                         monkeypatch):
+    from bench import run
+    root = _toy_root(tmp_path, {"name": "toy"})
+    real = loader.load_cell
+    monkeypatch.setattr(loader, "load_cell",
+                        lambda name: real(name, root=root))
+    assert run.main(["--workload", "toy.burst", "--seed", "1",
+                     "--seconds", "1"]) == 2

@@ -17,7 +17,7 @@ PUBLISHED = {"hidden_size": 64, "intermediate_size": 128,
              "tie_word_embeddings": True}
 
 CONFIG = {
-    "name": "tiny", "model": "smollm-135m",
+    "name": "tiny", "model": "smollm-135m", "arch": "dense",
     "model_overrides": {"n_layers": 2, "d_model": 64, "n_heads": 4,
                         "n_kv_heads": 2, "d_ff": 128, "vocab": 512},
     "published": PUBLISHED,
@@ -44,10 +44,12 @@ MIX = {
 def make_root(tmp: pathlib.Path, config: dict = CONFIG,
               mix: dict = MIX) -> pathlib.Path:
     """A benchmark root holding one cell, ``tiny.tiny-mix``, with the
-    repository's own readers."""
+    repository's own readers and architecture files."""
     (tmp / "bench" / "configs").mkdir(parents=True)
     (tmp / "bench" / "traffic").mkdir()
     shutil.copytree(BENCH / "metrics", tmp / "bench" / "metrics")
+    shutil.copytree(BENCH / "archs", tmp / "bench" / "archs",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     (tmp / "bench" / "configs" / "tiny.json").write_text(json.dumps(config))
     (tmp / "bench" / "traffic" / "tiny-mix.json").write_text(json.dumps(mix))
     spec = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
