@@ -10,6 +10,7 @@
 #   flash_attention - the LM-scale perf-critical kernel (VMEM-tiled online
 #                     softmax; eliminates the score-tile HBM traffic the
 #                     dry-run roofline exposes)
-#   ragged_decode   - serving decode attention: K/V blocks are read only up
-#                     to each slot's position (scalar-prefetch clamp) instead
-#                     of masking all of Smax — the fleet's hot path
+#   ragged_decode   - serving decode attention: one grid step per slot loops
+#                     over that slot's live K/V blocks only, read ahead by
+#                     manual DMA across slots, and writes the token's K/V in
+#                     place — the fleet's hot path

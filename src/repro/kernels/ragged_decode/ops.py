@@ -36,15 +36,16 @@ def force_pallas(enable: bool = True):
 def ragged_decode_attention(q: jax.Array, k_cache: jax.Array,
                             v_cache: jax.Array, k_new: jax.Array,
                             v_new: jax.Array, pos: jax.Array, layer, *,
-                            block_k: int = 128):
+                            block_k: int | None = None):
     """One-token GQA attention against a ragged batch cache, writing the
     token's K/V first.
 
     q: (B, Hq, hd); k,v caches: stacked (L, B, Hkv, hd, Smax); k_new, v_new:
     (B, Hkv, hd); pos: (B,) int32 index of each slot's newest token, where
     its K/V is written (a position past the cache writes nothing) and up to
-    which it attends; layer: int32 scalar.  Returns (out (B, Hq, hd)
-    float32, k_cache, v_cache).
+    which it attends; layer: int32 scalar.  ``block_k`` fixes the kernel's
+    block (tests); by default it follows the shapes.  Returns (out (B, Hq,
+    hd) float32, k_cache, v_cache).
     """
     on_tpu = jax.default_backend() == "tpu"
     if on_tpu or _FORCED:

@@ -171,7 +171,7 @@ def prefill_chunk(cfg: ModelConfig, p, tokens, cache, start, qlen):
 def decode(cfg: ModelConfig, p, token, pos, cache):
     """One decode step against (L, B, Hkv, hd, Smax) caches.  The stacked
     caches ride the scan carry and the decode kernel updates them in place
-    (one block per slot and head), so per-layer traffic is the attention
+    (one block per slot), so per-layer traffic is the attention
     read + a 1-token write.  ``pos``
     is a scalar or a per-slot (B,) vector — ragged batches decode each slot
     at its own position.  This is also the single-step body

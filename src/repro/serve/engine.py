@@ -798,7 +798,7 @@ class ServeEngine:
             # re-sync a long-idle slot's garbage decode would creep across
             # the whole cache and end up attending (and, on TPU, DMA'ing)
             # all of Smax every chunk — two tiny int32 uploads per step
-            # buy back the ragged clamp for partially-empty batches
+            # keep an idle slot at one read block in the kernel
             self._dev_dirty = True
         per_token = decode_elapsed / k
         self.last_step_latency = per_token

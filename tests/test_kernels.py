@@ -12,6 +12,7 @@ from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.matmul.kernel import matmul_pallas
 from repro.kernels.matmul.ref import matmul_ref
 from repro.kernels.ragged_decode import ops as ragged_decode_ops
+from repro.kernels.ragged_decode.kernel import read_block_rows
 from repro.kernels.ragged_decode.ref import ragged_decode_ref
 from repro.kernels.ragged_prefill import ops as ragged_prefill_ops
 from repro.kernels.ragged_prefill.ref import ragged_prefill_ref
@@ -108,6 +109,25 @@ def _decode_case(L, layer, Hq, Hkv, Smax, hd, bk, pos, tag=""):
     _decode_case(3, 2, 4, 2, 64, 32, 16, (32, 47, 63, 0, 64), "-layer2"),
     _decode_case(1, 0, 6, 2, 32, 16, 128, (0, 31, 17), "-one-block"),
     _decode_case(1, 0, 4, 2, 96, 16, 64, (5,), "-not-dividing"),
+    # mostly idle slots (pos 0-3) around two live ones over several blocks
+    _decode_case(2, 1, 4, 2, 128, 16, 16, (0, 1, 77, 2, 3, 0, 120, 1),
+                 "-mostly-idle"),
+    # the last row of a read block and the first of the next
+    _decode_case(2, 1, 4, 2, 128, 16, 32, (31, 32, 0, 95, 96),
+                 "-block-edges"),
+    # the cache's last row and a position past it, in one batch
+    _decode_case(2, 1, 4, 2, 64, 16, 32, (63, 64, 0, 100, 63),
+                 "-cache-end"),
+    # qwen2-0.5b's heads (rep 7, hd 64) in 256-row blocks
+    _decode_case(2, 1, 14, 2, 512, 64, 256,
+                 (0, 130, 300, 511, 1, 255, 256, 600, 127, 128),
+                 "-qwen2-heads"),
+    # the block the shapes give (f32: 128 rows at Smax 1024)
+    _decode_case(1, 0, 14, 2, 1024, 64, None, (700, 0, 1023, 5, 256),
+                 "-shape-block"),
+    # smollm-135m's heads: Hkv 3, rep 3
+    _decode_case(2, 0, 9, 3, 256, 64, 128, (0, 200, 3, 127, 128),
+                 "-smollm-heads"),
 ])
 def test_ragged_decode_parity(L, layer, Hq, Hkv, Smax, hd, bk, pos):
     """Pallas ragged decode attention (interpret mode, via force_pallas)
@@ -122,7 +142,7 @@ def test_ragged_decode_parity(L, layer, Hq, Hkv, Smax, hd, bk, pos):
     vn = jnp.asarray(RNG.standard_normal((B, Hkv, hd)), jnp.float32)
     pos = jnp.asarray(pos, jnp.int32)
     args = (q, k, v, kn, vn, pos, layer)
-    if Smax % min(bk, Smax):
+    if bk is not None and Smax % min(bk, Smax):
         # an aliased cache cannot be padded: the block must divide Smax
         with ragged_decode_ops.force_pallas(), pytest.raises(ValueError):
             ragged_decode_ops.ragged_decode_attention(*args, block_k=bk)
@@ -140,6 +160,26 @@ def test_ragged_decode_parity(L, layer, Hq, Hkv, Smax, hd, bk, pos):
                 want[layer, b, :, :, p] = np.asarray(new[b])
         for out in outs:
             np.testing.assert_array_equal(np.asarray(out), want)
+
+
+@pytest.mark.parametrize("n_kv,hd,smax,itemsize,rows", [
+    (2, 64, 4096, 2, 256),    # qwen2-0.5b, bf16: 128 KB blocks
+    (2, 64, 4096, 4, 128),    # the same heads in float32
+    (3, 64, 2048, 2, 128),    # smollm-135m: 170 rows fit, 128 divide
+    (8, 128, 4096, 2, 128),   # no 128-row block fits: 128 all the same
+    (2, 64, 384, 2, 128),     # 256 fits but does not divide 384
+    (2, 16, 64, 4, 64),       # a cache of at most 128 rows is one block
+])
+def test_ragged_decode_block_follows_shapes(n_kv, hd, smax, itemsize, rows):
+    """The decode kernel's block is the largest multiple of 128 dividing
+    the cache length within the K+V byte budget, 128 when none fits, and
+    the whole cache when it is at most 128 rows."""
+    assert read_block_rows(n_kv, hd, smax, itemsize) == rows
+
+
+def test_ragged_decode_block_refuses_unaligned_cache():
+    with pytest.raises(ValueError):
+        read_block_rows(2, 64, 4000, 2)
 
 
 @pytest.mark.parametrize("B,T,Hq,Hkv,Smax,hd,bk", [

@@ -121,6 +121,28 @@ def test_ragged_decode_kernel_compiles_for_v5e(one_chip):
     assert cache_shaped_ops(text, (B, HKV, SMAX, HD), 2) == []
 
 
+@pytest.mark.parametrize("b, hkv, rep, hd, smax, layers", [
+    (64, 2, 7, 64, 4096, 24),   # qwen2-0.5b.long-chat
+    (64, 8, 4, 128, 4096, 2),   # the hd-128, Hkv-8 families
+], ids=["qwen2-0.5b", "hkv8-hd128"])
+def test_long_chat_decode_kernel_compiles_for_v5e(one_chip, b, hkv, rep, hd,
+                                                  smax, layers):
+    """qwen2-0.5b.long-chat's own kernel shapes (B 64, Hkv 2, rep 7, hd 64,
+    Smax 4096, 24 layers), and the widest heads that decode through the
+    kernel (Hkv 8, hd 128), with the read block the shapes give: the read
+    buffers and write buffers fit the chip's VMEM, and no cache moves
+    outside the kernel."""
+    kv = _on(one_chip, (layers, b, hkv, hd, smax))
+    new = _on(one_chip, (b, hkv, hd))
+    compiled = jax.jit(ragged_decode_pallas, donate_argnums=(1, 2)).lower(
+        _on(one_chip, (b, hkv, rep, hd)), kv, kv, new, new,
+        _on(one_chip, (b,), jnp.int32), _on(one_chip, (), jnp.int32)
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert cache_shaped_ops(text, (b, hkv, smax, hd), layers) == []
+
+
 def test_ragged_prefill_kernel_compiles_for_v5e(one_chip):
     kv = _on(one_chip, (1, HKV, SMAX, HD))
     fn = lambda q, k, v, s, n: ragged_prefill_pallas(q, k, v, s, n, rep=REP)
