@@ -17,7 +17,7 @@ from . import common
 from . import (chaos_serving, disagg_serving, fig5_heatmap, fig6_kernels,
                fig7_speedup, fig8_interference, fig9_vgg_scaling,
                fig10_widths, fleet_routing, kernel_bench, pod_serving,
-               pod_straggler, region_routing, roofline, serve_decode)
+               pod_straggler, region_routing, roofline)
 
 MODULES = (
     ("chaos_serving", chaos_serving),
@@ -34,7 +34,6 @@ MODULES = (
     ("pod_straggler", pod_straggler),
     ("region_routing", region_routing),
     ("roofline", roofline),
-    ("serve_decode", serve_decode),
 )
 
 

@@ -1,6 +1,5 @@
 """Serve a small model with batched requests through the continuous-batching
-engine; the PTT-backed elastic scheduler handles prefill (critical) and
-decode (non-critical) placement.
+engine.
 
     PYTHONPATH=src python examples/serve_lm.py
 """
@@ -35,8 +34,6 @@ def main() -> None:
     for r in reqs[:3]:
         print(f"  req {r.rid}: prompt={r.prompt[:6].tolist()}... "
               f"-> {r.out_tokens}")
-    print(f"PTT updates observed by the serve scheduler: "
-          f"{engine.scheduler.ptt.updates}")
 
 
 if __name__ == "__main__":

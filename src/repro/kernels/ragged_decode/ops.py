@@ -2,8 +2,8 @@
 validation, jnp oracle fallback elsewhere.
 
 Unlike the other kernel wrappers this one is *not* jitted here — it is
-always traced inside a caller's jit (``Model.decode_jit`` /
-``Model.decode_fused``), and the backend choice is made at trace time.
+always traced inside a caller's jit (``Model.decode_fused``), and the
+backend choice is made at trace time.
 :func:`force_pallas` flips the choice for validation; because the decision
 is baked in at trace time, build a fresh :class:`~repro.models.Model`
 (fresh jit cache) inside the context to exercise the kernel end-to-end.

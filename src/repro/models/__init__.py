@@ -40,10 +40,6 @@ class Model:
     forward: Callable
     prefill: Callable
     decode: Callable
-    decode_jit: Callable          # jitted decode owned by this Model: every
-                                  # engine/replica built over it shares one
-                                  # compiled executable, and the executable's
-                                  # lifetime is the Model's (no global cache)
     decode_fused: Callable        # (params, token (B,1), pos (B,), cache, k)
                                   # -> (tokens (B,k), next_token, pos, cache)
                                   # greedy fast path: cache DONATED (updated
@@ -51,7 +47,9 @@ class Model:
                                   # after the call), argmax on device, k
                                   # decode steps per dispatch (lax.scan) —
                                   # one host sync per k tokens, not one
-                                  # logits transfer per token
+                                  # logits transfer per token; owned by this
+                                  # Model, so every engine built over it
+                                  # shares one compiled executable
     cache_spec: Callable
     cache_logical_axes: Callable
     cache_seq_axes: Callable
@@ -134,7 +132,6 @@ def get_model(cfg: ModelConfig) -> Model:
 
     return Model(cfg=cfg, init=bind(mod.init), forward=bind(mod.forward),
                  prefill=bind(mod.prefill), decode=bind(mod.decode),
-                 decode_jit=jax.jit(bind(mod.decode)),
                  decode_fused=_fused_decode(cfg, mod),
                  cache_spec=bind(mod.cache_spec),
                  cache_logical_axes=bind(mod.cache_logical_axes),

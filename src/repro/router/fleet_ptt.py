@@ -17,8 +17,7 @@ time** (``record_service``) — the :class:`~repro.core.tracetable.QueueAware`
 cost model turns backlog counts into *seconds of work ahead* with it, which
 is what lets PTT routing beat join-shortest-queue instead of merely
 matching it.  There is no width axis here: a replica is an opaque serving
-unit (its internal width elasticity is the
-:class:`~repro.serve.scheduler.ElasticServeScheduler`'s job).
+unit.
 
 All searches accept a :class:`~repro.core.tracetable.CostModel`; the
 defaults reproduce the classic behavior (QueueAware for global/ranked,

@@ -1,7 +1,6 @@
 """Ragged continuous-batching serving engine with serializable KV sessions.
 
-Real execution path (works on one CPU device with a reduced model; on a pod
-each width-w place holds a compiled executable pair):
+Real execution path (one CPU device with a reduced model, or one chip):
 
 * requests arrive with prompt tokens; **any free slot admits any queued
   prompt** regardless of length or current batch occupancy — prefill runs
@@ -10,24 +9,24 @@ each width-w place holds a compiled executable pair):
 * every engine step decodes a **chunk of ``decode_chunk`` tokens** for the
   whole active batch at **per-slot positions** (each slot masks/writes at
   its own position, so a slot admitted mid-flight decodes next to slots
-  deep into generation).  The default path is ``Model.decode_fused``: the
-  cache is *donated* into the jit (updated in place — no per-token copy of
-  every layer's KV), greedy sampling runs on device, and ``cur_token`` /
+  deep into generation).  The one decode path is ``Model.decode_fused``:
+  the cache is *donated* into the jit (updated in place — no per-token copy
+  of every layer's KV), greedy sampling runs on device, and ``cur_token`` /
   ``pos`` stay device-resident between chunks — the only host transfer per
   step is the ``(B, k)`` block of token ids.  A slot that reaches its
   ``max_new`` (or the cache edge) mid-chunk keeps only the tokens up to
-  that point; the surplus the chunk decoded past it is truncated.
-  ``fused=False`` keeps the legacy per-token path (undonated
-  ``Model.decode_jit`` + host argmax) for A/B benchmarking;
+  that point; the surplus the chunk decoded past it is truncated;
 * finished sequences (max_new reached) free their slots immediately;
 * a live request can leave the engine as a :class:`Session`
   (``export_session``) — tokens, position, and its KV/state slice pulled to
   host numpy — and resume on another engine (``import_session``), which is
   how the fleet gateway drains a quarantined replica without killing its
-  in-flight work;
-* the :class:`ElasticServeScheduler` is consulted per prefill (critical) and
-  per decode batch (non-critical) so the PTT learns group/width latencies —
-  on one device the decision is degenerate but the full control path runs.
+  in-flight work.
+
+The engine makes no placement decision: each step's per-token decode
+latency and each prefill chunk's latency go out through
+``on_step_latency`` / ``on_prefill_latency`` to the fleet gateway, whose
+``FleetPTT`` picks replicas.
 """
 
 from __future__ import annotations
@@ -43,7 +42,6 @@ import jax.numpy as jnp
 
 from ..models import Model
 from ..obs import NULL_TRACER
-from .scheduler import ElasticServeScheduler
 
 
 @dataclasses.dataclass
@@ -111,8 +109,7 @@ class _Prefill:
 
 class ServeEngine:
     def __init__(self, model: Model, params, max_batch: int, max_seq: int,
-                 num_groups: int = 1, decode_chunk: int = 1,
-                 fused: bool = True, role: str = "both",
+                 decode_chunk: int = 1, role: str = "both",
                  prefill_chunk_tokens: int = 0):
         if role not in ("prefill", "decode", "both"):
             raise ValueError(f"unknown role {role!r}")
@@ -129,7 +126,6 @@ class ServeEngine:
         self.max_batch = max_batch
         self.max_seq = max_seq
         self.decode_chunk = max(int(decode_chunk), 1)
-        self.fused = fused
         # disaggregation surface: ``role`` is the replica's specialization
         # (a scheduling preference the gateway routes by — the engine stays
         # fully capable either way).  ``prefill_chunk_tokens`` > 0 admits
@@ -142,7 +138,6 @@ class ServeEngine:
         # chaos surface: a crashed engine serves nothing until restart()
         # (see crash() — fault injection / process death stand-in)
         self.crashed = False
-        self.scheduler = ElasticServeScheduler(num_groups)
         self.queue: deque[Request] = deque()
         self.sessions_in: deque[Session] = deque()   # imported, not yet slotted
         self.prefilling: deque[_Prefill] = deque()   # chunked prefills in
@@ -154,16 +149,15 @@ class ServeEngine:
         self.cache = None
         self.pos = np.zeros(max_batch, dtype=np.int32)
         self.cur_token = np.zeros((max_batch, 1), dtype=np.int32)
-        # device-resident mirrors of cur_token/pos for the fused path: they
-        # ride the decode outputs between chunks and are re-uploaded from
-        # the host arrays only after a slot-changing event (admission,
-        # finish, export) marks them dirty
+        # device-resident mirrors of cur_token/pos: they ride the decode
+        # outputs between chunks and are re-uploaded from the host arrays
+        # only after a slot-changing event (admission, finish, export)
+        # marks them dirty
         self._dev_tok = None
         self._dev_pos = None
         self._dev_dirty = True
-        # the Model owns the jitted decodes: replicas sharing a Model share
-        # the compiled executables, and they die with the Model
-        self._decode = model.decode_jit
+        # the Model owns the jitted decode: replicas sharing a Model share
+        # the compiled executable, and it dies with the Model
         self._decode_fused = model.decode_fused
         # fleet surface (router/gateway): called with each step's *decode*
         # latency normalized **per token** (elapsed / decode_chunk), so the
@@ -416,7 +410,6 @@ class ServeEngine:
             moved += 1
             t0 = time.perf_counter()
             req.t_admit = t0
-            d = self.scheduler.schedule_prefill(len(req.prompt))
             batch = {"tokens": np.array(req.prompt, np.int32)[None, :]}
             for name, val in req.extras.items():
                 batch[name] = np.array(val)[None]
@@ -424,7 +417,6 @@ class ServeEngine:
             logits, cache = self.model.prefill(self.params, batch)
             next_tok = int(jnp.argmax(logits[0, -1]))
             prefill_dur = time.perf_counter() - t0
-            self.scheduler.record(d, prefill_dur, time.perf_counter())
             if self.tracer.enabled:
                 tid = self.tracer.trace_for(req.rid)
                 if tid is not None:
@@ -457,7 +449,6 @@ class ServeEngine:
         if pf.consumed == 0:
             pf.req.t_admit = t0      # its service starts with this chunk
         with self.tracer.track_span("engine.prefill.dispatch", self.obs_name):
-            d = self.scheduler.schedule_prefill(qlen)
             chunk = np.zeros((1, C), np.int32)
             chunk[0, :qlen] = prompt[pf.consumed:pf.consumed + qlen]
             tokens, start, live = jax.device_put(
@@ -472,7 +463,6 @@ class ServeEngine:
             with self.tracer.track_span("engine.prefill.sync", self.obs_name):
                 next_tok = int(jnp.argmax(logits[0, -1]))  # chunk's host sync
         dur = time.perf_counter() - t0
-        self.scheduler.record(d, dur, time.perf_counter())
         self.last_prefill_chunk_latency = dur
         if self._h_prefill_chunk is not None:
             self._h_prefill_chunk.observe(dur)
@@ -691,16 +681,15 @@ class ServeEngine:
         chunk for the batch at per-slot positions.  Returns number of active
         sequences.
 
-        Fused path (default): one ``Model.decode_fused`` dispatch decodes
-        the whole chunk with the cache donated (in-place update) and greedy
-        sampling on device; the ``(B, k)`` token ids are the chunk's single
-        host transfer.  Slots that finish mid-chunk keep only their tokens
-        up to the finish; the surplus the chunk decoded past it is
-        truncated (and the freed slot is re-synced to device via the dirty
-        flag before the next chunk).  ``last_step_latency`` and the
-        ``on_step_latency`` hook report the decode latency **per token**
-        (elapsed / chunk), keeping the interference signal comparable
-        across chunk sizes.
+        One ``Model.decode_fused`` dispatch decodes the whole chunk with the
+        cache donated (in-place update) and greedy sampling on device; the
+        ``(B, k)`` token ids are the chunk's single host transfer.  Slots
+        that finish mid-chunk keep only their tokens up to the finish; the
+        surplus the chunk decoded past it is truncated (and the freed slot
+        is re-synced to device via the dirty flag before the next chunk).
+        ``last_step_latency`` and the ``on_step_latency`` hook report the
+        decode latency **per token** (elapsed / chunk), keeping the
+        interference signal comparable across chunk sizes.
 
         With a tracer attached, the call is an ``engine.step`` span on this
         engine's track (args: the batch and backlog at its start) holding
@@ -741,46 +730,24 @@ class ServeEngine:
             self._g_queue.set(float(self.pending()))
         if n_active == 0:
             return 0
-        d = self.scheduler.schedule_decode(group=0)
         t0 = time.perf_counter()
         if self._dev_dirty or self._dev_tok is None:
-            # both paths keep cur_token/pos device-resident between steps;
-            # this re-upload runs only after a slot-changing event
+            # cur_token/pos stay device-resident between steps; this
+            # re-upload runs only after a slot-changing event
             # (admission, finish, export) marked them dirty
             with self.tracer.track_span("engine.upload", self.obs_name):
                 self._dev_tok, self._dev_pos = jax.device_put(
                     (self.cur_token, self.pos), self.device)
             self._dev_dirty = False
-        if self.fused:
-            k = self.decode_chunk
-            with self.tracer.track_span("engine.decode.dispatch",
-                                        self.obs_name):
-                toks_dev, self._dev_tok, self._dev_pos, self.cache = (
-                    self._decode_fused(self.params, self._dev_tok,
-                                       self._dev_pos, self.cache, k))
-            # the chunk's ONE host sync: a (B, k) block of token ids
-            with self.tracer.track_span("engine.decode.sync", self.obs_name):
-                toks = np.asarray(toks_dev)  # analysis: allow-host-sync(the one sanctioned sync per decode chunk)
-        else:
-            # legacy per-step path (A/B baseline): undonated decode, but
-            # cur_token/pos stay device-resident with the same dirty-resync
-            # scheme as the fused path — argmax runs on device and only the
-            # (B, 1) token ids cross to host, not the full logits row plus
-            # a cur_token re-upload every step
-            k = 1
-            with self.tracer.track_span("engine.decode.dispatch",
-                                        self.obs_name):
-                logits, self.cache = self._decode(
-                    self.params, self._dev_tok, self._dev_pos, self.cache)
-                nxt = jnp.argmax(logits[:, 0], axis=-1).astype(
-                    jnp.int32)[:, None]
-                self._dev_tok = nxt
-                self._dev_pos = self._dev_pos + 1
-            # the step's ONE host sync: the (B, 1) block of token ids
-            with self.tracer.track_span("engine.decode.sync", self.obs_name):
-                toks = np.asarray(nxt)  # analysis: allow-host-sync(the one sanctioned sync per legacy step)
+        k = self.decode_chunk
+        with self.tracer.track_span("engine.decode.dispatch", self.obs_name):
+            toks_dev, self._dev_tok, self._dev_pos, self.cache = (
+                self._decode_fused(self.params, self._dev_tok, self._dev_pos,
+                                   self.cache, k))
+        # the chunk's ONE host sync: a (B, k) block of token ids
+        with self.tracer.track_span("engine.decode.sync", self.obs_name):
+            toks = np.asarray(toks_dev)  # analysis: allow-host-sync(the one sanctioned sync per decode chunk)
         decode_elapsed = time.perf_counter() - t0
-        self.scheduler.record(d, decode_elapsed, time.perf_counter())
         if self.tracer.enabled:
             # one span per active request per chunk, before the harvest
             # loop nulls finished slots — every request's timeline shows
@@ -793,7 +760,7 @@ class ServeEngine:
         with self.tracer.track_span("engine.harvest", self.obs_name):
             self._harvest(toks, k)
         if any(r is None for r in self.active):
-            # keep idle slots' device pos pinned at 0: both paths advance
+            # keep idle slots' device pos pinned at 0: the decode advances
             # every slot's device pos unconditionally, so without this
             # re-sync a long-idle slot's garbage decode would creep across
             # the whole cache and end up attending (and, on TPU, DMA'ing)

@@ -12,6 +12,10 @@ The PTT learns per-(group, width) latencies online, so a slow group (co-
 tenant interference, thermal throttling, a degraded ICI link) stops
 receiving critical prefills within a few EMA updates and recovers the same
 way — no platform knowledge required, which is the paper's core claim.
+
+The request classes and their classifiers are shared with the fleet
+router, admission and region code; :class:`ElasticServeScheduler` itself
+drives only the pod-scale simulator.
 """
 
 from __future__ import annotations
@@ -52,6 +56,11 @@ class Decision:
 
 
 class ElasticServeScheduler:
+    """The pod-scale simulator's scheduler (``benchmarks/pod_serving.py``):
+    a :class:`PodPTT` over device groups.  The serving engine does not
+    consult it; a fleet's replicas are picked by the router's ``FleetPTT``.
+    """
+
     def __init__(self, num_groups: int):
         self.ptt = PodPTT(num_groups, num_task_types=len(RequestClass))
 

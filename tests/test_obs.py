@@ -358,9 +358,8 @@ def test_fleet_benchmark_every_decision_carries_a_valid_breakdown():
 # ---------------------------------------------------------------------------
 
 class _NullModel:
-    """ServeEngine.__init__ only reads the jitted decode handles; a stats
-    facade test never steps the engine, so None handles suffice."""
-    decode_jit = None
+    """ServeEngine.__init__ only reads the jitted decode handle; a stats
+    facade test never steps the engine, so a None handle suffices."""
     decode_fused = None
 
 

@@ -121,8 +121,6 @@ def test_engine_queueing_more_requests_than_slots():
     engine.run_until_drained(max_steps=200)
     assert all(r.done for r in reqs)
     assert all(len(r.out_tokens) >= 4 for r in reqs)
-    # the PTT saw both prefill (critical) and decode (non-critical) updates
-    assert engine.scheduler.ptt.updates > len(reqs)
 
 
 def test_engine_lives_on_its_params_device(subproc):
